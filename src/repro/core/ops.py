@@ -2,8 +2,8 @@
 
 The TURNIP runtime is kernel-agnostic: a TASKGRAPH vertex names an op in this
 registry (paper: cuTensor calls / hand-written CUDA kernels; here: numpy
-kernels on the CPU container, with the Pallas TPU kernels in
-:mod:`repro.kernels` registered under the same names for TPU targets).
+kernels that run on the host). No device kernel is registered: the Pallas
+kernels in :mod:`repro.kernels` are not reachable from this registry.
 
 Every op is a pure function ``f(*operand_values, **params) -> np.ndarray``.
 Ops must be deterministic given their operands so that any dependency-

@@ -277,7 +277,9 @@ class DiskStore:
         payload = value if isinstance(value, dict) else {self._ARR: value}
         arrays = {k: np.ascontiguousarray(np.asarray(v))
                   for k, v in payload.items()}
-        spec = tuple((k, a.dtype.str, a.shape, a.nbytes)
+        # the dtype object itself, not its '<V2'-style string: extended
+        # dtypes (bfloat16, float8_*) must read back as themselves
+        spec = tuple((k, a.dtype, a.shape, a.nbytes)
                      for k, a in arrays.items())
         blob = b"".join(a.tobytes() for a in arrays.values())
         n = len(blob)

@@ -49,10 +49,15 @@ class Heartbeat:
         self.last_beat: dict[str, float] = {}
         self._lock = lockcheck.make_lock("Heartbeat")
 
-    def beat(self, worker: str, now: float | None = None) -> None:
+    def beat(self, worker: str, now: float | None = None, *,
+             grace_s: float = 0.0) -> None:
+        """Record a beat. ``grace_s`` announces a bounded silence (a
+        compile): the worker is dead only after ``grace_s + timeout_s``
+        without another beat. The stamp kept is the beat pushed forward
+        by the grace."""
         stamp = time.monotonic() if now is None else now
         with self._lock:
-            self.last_beat[worker] = stamp
+            self.last_beat[worker] = stamp + grace_s
 
     def dead_workers(self, now: float | None = None) -> list[str]:
         now = time.monotonic() if now is None else now

@@ -27,7 +27,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, s_scr, *,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     lw = lw_ref[0, 0].astype(jnp.float32)           # [c, P] (log decay ≤ 0)
-    u = u_ref[0].astype(jnp.float32)                # [P]
+    u = u_ref[pl.program_id(1)].astype(jnp.float32)   # this head's bonus [P]
 
     lcw = jnp.cumsum(lw, axis=0)                    # [c, P]
     prev = lcw - lw
@@ -69,7 +69,8 @@ def wkv6_kernel(r, k, v, lw, u, *, chunk: int = 32,
         grid=(B, H, nc),
         in_specs=[pl.BlockSpec((1, 1, chunk, P),
                                lambda b, h, ic: (b, h, ic, 0))] * 4
-        + [pl.BlockSpec((1, P), lambda b, h, ic: (h, 0))],
+        # u whole: a (1, P) block breaks the 8-sublane tiling rule
+        + [pl.BlockSpec((H, P), lambda b, h, ic: (0, 0))],
         out_specs=pl.BlockSpec((1, 1, chunk, P),
                                lambda b, h, ic: (b, h, ic, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, P), r.dtype),

@@ -26,7 +26,7 @@ def _ssd_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, y_ref, h_scr, *,
 
     x = x_ref[0, 0].astype(jnp.float32)            # [c, P]
     dt = dt_ref[0, 0].astype(jnp.float32)          # [c, 1] (lane-padded)
-    a = A_ref[0].astype(jnp.float32)               # scalar decay rate
+    a = A_ref[pl.program_id(1)].astype(jnp.float32)   # this head's decay rate
     Bm = B_ref[0].astype(jnp.float32)              # [c, N]
     Cm = C_ref[0].astype(jnp.float32)              # [c, N]
 
@@ -73,7 +73,9 @@ def ssd_scan_kernel(xh, dt, A, Bm, Cm, *, chunk: int = 128,
         in_specs=[
             pl.BlockSpec((1, 1, chunk, P), lambda b, h, ic: (b, h, ic, 0)),
             pl.BlockSpec((1, 1, chunk, 1), lambda b, h, ic: (b, h, ic, 0)),
-            pl.BlockSpec((1,), lambda b, h, ic: (h,)),
+            # A whole in SMEM: a rank-1 (1,) VMEM block breaks the 128-lane
+            # tiling, and the kernel only needs one scalar of it per head
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, N), lambda b, h, ic: (b, ic, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, ic: (b, ic, 0)),
         ],

@@ -1,10 +1,7 @@
 """Production mesh builders + fleet topology.
 
 A function, not a module-level constant: importing this module must never
-touch jax device state (the dry-run sets XLA_FLAGS before any jax import) —
-the jax imports themselves are deferred into the mesh builders, so the
-fleet-topology half of the module (consumed by serve/router.py) stays
-importable even where the installed jax predates ``AxisType``.
+touch jax device state (the dry-run sets XLA_FLAGS before any jax import).
 
 Besides the single-host device meshes, this module describes the
 *fleet*: an N-replica serving topology (one serving engine + host/disk
@@ -15,6 +12,9 @@ serving)."""
 from __future__ import annotations
 
 import dataclasses
+
+import jax
+from jax.sharding import AxisType
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,8 +57,6 @@ def make_production_mesh(*, multi_pod: bool = False,
     dry-run. Axes: ('pod',) 'data', 'model'. ``shape`` overrides the
     per-pod (data, model) factorization — e.g. (32, 8) suits archs whose
     head counts divide 8 but not 16 (§Perf iteration A4)."""
-    import jax
-    from jax.sharding import AxisType
     if shape is None:
         shape = (2, 16, 16) if multi_pod else (16, 16)
     elif multi_pod and len(shape) == 2:
@@ -71,7 +69,5 @@ def make_production_mesh(*, multi_pod: bool = False,
 
 def make_smoke_mesh():
     """Single-device mesh with the production axis names (CPU tests)."""
-    import jax
-    from jax.sharding import AxisType
     return jax.make_mesh((1, 1), ("data", "model"),
                          axis_types=(AxisType.Auto, AxisType.Auto))

@@ -25,6 +25,7 @@ from ..models.lora import lora_init, make_lora_loss
 from ..train.optim import AdamW
 from ..train.step import init_train_state, make_train_step
 from ..ckpt.store import latest_step, restore_checkpoint
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -42,6 +43,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--remat", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -1,9 +1,9 @@
 """Transformer building blocks (pure functional JAX).
 
 Everything here is shape-polymorphic, scan-friendly and GSPMD-compatible.
-Attention uses an online-softmax *blockwise* formulation by default (no
-[S, S] materialization — mandatory for the 32k prefill shapes), switchable to
-the Pallas flash kernel via ``use_pallas`` for TPU targets.
+Attention uses an online-softmax *blockwise* formulation (no [S, S]
+materialization — mandatory for the 32k prefill shapes). The Pallas flash
+kernel in :mod:`repro.kernels.flash_attention` is not wired in here.
 """
 from __future__ import annotations
 

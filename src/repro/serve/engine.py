@@ -164,8 +164,9 @@ class ServeConfig:
     # admission, within the host budget's free headroom (timing only —
     # tokens never depend on it)
     prefetch_swapped: bool = True
-    # simulated PCIe (the container has no accelerator; wire time is slept
-    # on the DMA thread, exactly like TurnipRuntime's `latency` injection)
+    # simulated wire time, slept on the DMA thread ON TOP of the real
+    # device<->host copies (like TurnipRuntime's `latency` injection); set
+    # dma_latency=0 and infinite bandwidths to sleep nothing
     h2d_bw: float = 12e9
     d2h_bw: float = 12e9
     dma_latency: float = 10e-6
@@ -449,8 +450,12 @@ class Engine:
 
     def __init__(self, model, params, cfg: ServeConfig = ServeConfig(), *,
                  host: HostStore | None = None, pool=None,
-                 name: str = "serve"):
-        """``host``: pass a runtime's :class:`HostStore` (or
+                 name: str = "serve", device=None):
+        """``device``: the accelerator this replica owns (default: the
+        first device JAX sees). Params, the KV cache and every host→device
+        copy live there; a router hands each replica its own.
+
+        ``host``: pass a runtime's :class:`HostStore` (or
         :class:`TieredStore`) to share one pinned host pool (and its
         traffic counters) with it; by default the engine owns a private
         arena — tiered (host + disk) when ``cfg.host_kv_bytes`` bounds the
@@ -482,7 +487,8 @@ class Engine:
             raise ValueError("shared store already lease-attached: pool "
                              "arbitration would double-count its bytes")
         self.model = model
-        self.params = params
+        self.device = device if device is not None else jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
         self.cfg = cfg
         self.name = name            # replica identity (router + diagnostics)
         self._pool = pool
@@ -543,10 +549,11 @@ class Engine:
         self._live: set[int] = set()                # rids not yet DONE
         self.stats = ServeStats()
         self.kv: PagedKVCache | None = None
-        # single jit wrappers: jax.jit retraces per input shape, so one
-        # wrapper covers every batch bucket / prompt pad length
+        # one jit wrapper per program, compiled per argument signature
+        # (batch bucket, prompt pad length) by _run_program
         self._step = jax.jit(model.decode_step)
         self._prefill = jax.jit(model.prefill)
+        self._programs: dict = {}
         self._next_rid = 0
         self._queue: list[int] = []                 # QUEUED rids, FIFO
         self._swapped: list[int] = []               # SWAPPED rids, FIFO
@@ -569,6 +576,15 @@ class Engine:
         # the router wires it to Heartbeat.beat(replica), so a wedged or
         # paused loop stops beating and the supervisor notices.
         self.on_step = None
+        # on_compile: called on the run-loop thread just before a model
+        # program compiles for a new shape — seconds of silence at full
+        # size, which the router announces to the heartbeat as a grace
+        # period instead of mistaking it for a wedge.
+        self.on_compile = None
+        # on_token: called under the engine lock with each request whose
+        # token was just appended and the logit row it was sampled from —
+        # how a check compares the engine's logits with the oracle's.
+        self.on_token = None
         # hard-kill seams: `hard_kill()` (async, from any thread) or
         # `fault_after_steps` (deterministic: raise once this many decode
         # steps have run — the chaos harness's seeded kill instants). Both
@@ -765,28 +781,12 @@ class Engine:
         admission."""
         if req.state != SWAPPED or req.inflight or req.pending_reload:
             return None
-        # the disk tier stores raw bytes and restores extended dtypes
-        # (bfloat16, float8_*) as anonymous void words — relabel them from
-        # the cache's own leaves so the ticket carries true dtypes and the
-        # destination's leaf-spec validation sees what it expects. A view,
-        # never a cast: the bytes are already exact.
-        dtypes = {k: np.dtype(leaf.dtype)
-                  for k, leaf in self.kv.cache.items()}
         blocks = []
         for blk in range(self.kv.n_token_blocks(req.pos)):
             data = self.host.peek_offload((req.rid, blk))
             if data is None:
                 return None
-            fixed = {}
-            for k, v in data.items():
-                arr = np.asarray(v)
-                want = dtypes.get(k)
-                if (want is not None and arr.dtype != want
-                        and arr.dtype.kind == "V"
-                        and arr.dtype.itemsize == want.itemsize):
-                    arr = arr.view(want)
-                fixed[k] = arr
-            blocks.append(fixed)
+            blocks.append({k: np.asarray(v) for k, v in data.items()})
         return blocks
 
     def _ticket_locked(self, req: Request,
@@ -886,7 +886,8 @@ class Engine:
                 # leaf spec) is needed before any payload can be validated
                 bucket = self._bucket_for(1)
                 self.kv = PagedKVCache(self.model, bucket, self.cfg.max_len,
-                                       block_size=self.cfg.block_size)
+                                       block_size=self.cfg.block_size,
+                                       device=self.device)
                 self._slots = [None] * bucket
             n_blocks = self.kv.n_token_blocks(ticket.pos)
             if len(ticket.blocks) != n_blocks:
@@ -1014,6 +1015,14 @@ class Engine:
         finally:
             with self._lock:
                 for stream in streams:
+                    # an abandoned transfer never reaches its service hook:
+                    # a finished request's reservation (an eager mirror
+                    # still queued when its last token landed) would be
+                    # held past the run forever
+                    for tr in stream.pending:
+                        req = self.reqs.get(tr.rid)
+                        if req is None or req.state == DONE:
+                            self._release_key_locked((tr.rid, tr.blk))
                     stream.shutdown()
                 self._spill_inflight.clear()
                 self._prefetch_inflight.clear()
@@ -1230,7 +1239,7 @@ class Engine:
         if self.kv is None:
             self.kv = PagedKVCache(
                 self.model, desired, self.cfg.max_len,
-                block_size=self.cfg.block_size)
+                block_size=self.cfg.block_size, device=self.device)
             self._slots = [None] * desired
         elif desired > self.kv.bucket:
             self.kv.grow(desired)
@@ -1318,6 +1327,23 @@ class Engine:
                                 self._block_seq[key], self.kv.block_nbytes,
                                 disk_op=disk_op))
 
+    # ------------------------------------------------------ model programs
+    def _run_program(self, fn, *args):
+        """Run jitted ``fn`` on ``(self.params, *args)``. The first call at
+        a new argument signature compiles explicitly, announced through
+        ``on_compile``. The key skips ``params``: they are fixed for the
+        engine's life."""
+        key = (fn, tuple((a.shape, a.dtype) for a in jax.tree.leaves(args)))
+        exe = self._programs.get(key)
+        if exe is None:
+            if self.on_compile is not None:
+                self.on_compile(self)
+            exe = self._programs[key] = self._compile(fn, args)
+        return exe(self.params, *args)
+
+    def _compile(self, fn, args):
+        return fn.lower(self.params, *args).compile()
+
     # ------------------------------------------------------------ prefill
     def _prefill_admit(self, admits: list[tuple[int, int]]) -> None:
         """One batched forward over the admitted prompts (padded to a
@@ -1335,8 +1361,8 @@ class Engine:
             toks[i, :len(r.prompt)] = r.prompt
             lengths[i] = len(r.prompt)
         t0 = time.perf_counter()
-        logits, kv = self._prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(lengths))
+        logits, kv = self._run_program(self._prefill, self.kv.put(toks),
+                                       self.kv.put(lengths))
         logits_np = np.asarray(logits, np.float32)
         self.stats.prefill_time += time.perf_counter() - t0
         with self._lock:
@@ -1358,6 +1384,8 @@ class Engine:
                             vocab_size=self.model.cfg.vocab_size)
         req.out.append(tok)
         req.last = tok
+        if self.on_token is not None:
+            self.on_token(req, row_logits)
         if req.t_first == 0.0:      # a migrated request keeps its original
             req.t_first = time.monotonic()   # first-token stamp (ticket)
         self.stats.tokens += 1
@@ -1584,8 +1612,9 @@ class Engine:
                 lens[slot] = req.pos
                 mask[slot] = True
         t0 = time.perf_counter()
-        logits, new_cache = self._step(self.params, cache, jnp.asarray(toks),
-                                       jnp.asarray(lens), jnp.asarray(mask))
+        put = self.kv.put
+        logits, new_cache = self._run_program(self._step, cache, put(toks),
+                                              put(lens), put(mask))
         logits_np = np.asarray(logits, np.float32)
         self.stats.decode_time += time.perf_counter() - t0
         self.stats.decode_steps += 1
@@ -1655,11 +1684,23 @@ class Engine:
 # --------------------------------------------------------------------------
 def naive_generate(model, params, prompt, *, max_new: int = 32,
                    max_len: int = 512, rid: int = 0, seed: int = 0,
-                   temperature: float = 0.0) -> list[int]:
+                   temperature: float = 0.0, return_logits: bool = False,
+                   force: list[int] | None = None):
     """Reference decode for ONE request, no batching/padding/offload: one
     prefill forward, then single-row decode steps, sampling with the same
     (seed, rid, position) key schedule as the engine. ``Engine.generate``
-    must reproduce this for every batching and offload configuration."""
+    must reproduce this for every batching and offload configuration.
+
+    ``return_logits``: return ``(tokens, rows)``, where ``rows[i]`` is the
+    float32 logit row (vocab padding cut) token ``i`` was sampled from —
+    what a low-precision comparison needs to tell a near-tie from a
+    real divergence.
+
+    ``force``: feed these tokens to the next steps in place of the sampled
+    ones (teacher forcing). ``tokens[i]`` and ``rows[i]`` then score
+    position ``i`` of another decode's output given its own prefix, so
+    that decode is checked at every position, not only up to its first
+    departure."""
     prompt = [int(t) for t in prompt]
     p_len = len(prompt)
     vocab = model.cfg.vocab_size
@@ -1677,14 +1718,19 @@ def naive_generate(model, params, prompt, *, max_new: int = 32,
     cache = {k: cache[k].at[:, :, :p_len].set(kv[k].astype(cache[k].dtype))
              for k in cache}
     out: list[int] = []
+    rows: list[np.ndarray] = []
     pos = p_len
     row = np.asarray(logits[0], np.float32)
     while True:
         tok = _sample_token(row, seed=seed, rid=rid, pos=pos,
                             temperature=temperature, vocab_size=vocab)
         out.append(tok)
+        if return_logits:
+            rows.append(row[:vocab])
         if len(out) >= max_new or pos >= max_len:
-            return out
+            return (out, rows) if return_logits else out
+        if force is not None and len(out) <= len(force):
+            tok = int(force[len(out) - 1])
         logits, cache = step(params, cache,
                              jnp.asarray([[tok]], jnp.int32),
                              jnp.asarray([pos], jnp.int32))

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -49,14 +50,22 @@ class PagedKVCache:
     stays consistent on a DMA thread)."""
 
     def __init__(self, model, bucket: int, max_len: int, *,
-                 block_size: int = 32) -> None:
+                 block_size: int = 32, device=None) -> None:
+        """``device``: where the cache lives and every host→device copy
+        lands (default: the first device JAX sees)."""
         if max_len % block_size:
             raise ValueError("max_len must be a multiple of block_size")
         self.model = model
         self.bucket = bucket
         self.max_len = max_len
         self.block_size = block_size
-        self.cache: dict[str, Any] = model.init_cache(bucket, max_len)
+        self.device = device if device is not None else jax.devices()[0]
+        # built on the device itself: a cache made on the default device
+        # and then moved would pass through (and briefly double on) it
+        self.cache: dict[str, Any] = jax.jit(
+            model.init_cache, static_argnums=(0, 1),
+            out_shardings=jax.sharding.SingleDeviceSharding(self.device),
+        )(bucket, max_len)
         for name, leaf in self.cache.items():
             if leaf.ndim < 3 or leaf.shape[1] != bucket \
                     or leaf.shape[2] != max_len:
@@ -107,10 +116,14 @@ class PagedKVCache:
         return {k: np.asarray(leaf[:, slot, lo:hi])
                 for k, leaf in leaves.items()}
 
+    def put(self, x) -> jax.Array:
+        """Copy a host array onto the cache's device."""
+        return jax.device_put(x, self.device)
+
     def write_block(self, slot: int, blk: int,
                     data: dict[str, np.ndarray]) -> None:
         lo, hi = self.token_range(blk)
-        self.cache = {k: leaf.at[:, slot, lo:hi].set(jnp.asarray(data[k]))
+        self.cache = {k: leaf.at[:, slot, lo:hi].set(self.put(data[k]))
                       for k, leaf in self.cache.items()}
 
     def restore_slot(self, slot: int,
@@ -120,18 +133,18 @@ class PagedKVCache:
         per block."""
         span = len(blocks) * self.block_size
         self.cache = {
-            k: leaf.at[:, slot, :span].set(
-                jnp.concatenate([jnp.asarray(b[k]) for b in blocks],
-                                axis=1).astype(leaf.dtype))
+            k: leaf.at[:, slot, :span].set(self.put(
+                np.concatenate([np.asarray(b[k]) for b in blocks],
+                               axis=1)).astype(leaf.dtype))
             for k, leaf in self.cache.items()}
 
     def drop_slot(self, slot: int) -> None:
-        self.cache = {k: leaf.at[:, slot].set(jnp.zeros((), leaf.dtype))
+        self.cache = {k: leaf.at[:, slot].set(0)
                       for k, leaf in self.cache.items()}
 
     def scatter_prefill(self, slots: list[int], kv: dict[str, Any]) -> None:
         """Write prefill K/V (leaves [L, len(slots), S, ...]) into rows."""
-        idx = jnp.asarray(slots)
+        idx = self.put(np.asarray(slots, np.int32))
         S = next(iter(kv.values())).shape[2]
         self.cache = {k: leaf.at[:, idx, :S].set(kv[k].astype(leaf.dtype))
                       for k, leaf in self.cache.items()}
@@ -141,9 +154,6 @@ class PagedKVCache:
         if pad <= 0:
             return
         self.cache = {
-            k: jnp.concatenate(
-                [leaf,
-                 jnp.zeros(leaf.shape[:1] + (pad,) + leaf.shape[2:],
-                           leaf.dtype)], axis=1)
+            k: jnp.pad(leaf, [(0, 0), (0, pad)] + [(0, 0)] * (leaf.ndim - 2))
             for k, leaf in self.cache.items()}
         self.bucket = new_bucket

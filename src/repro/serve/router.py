@@ -29,7 +29,9 @@ not just N engines:
   of ``prompt + out`` — token-exact either way.
 
 * **Drain** — each replica's run loop beats a
-  :class:`~repro.ft.supervisor.Heartbeat`; a replica that crashes
+  :class:`~repro.ft.supervisor.Heartbeat` (and announces each model
+  compile as a bounded grace, :data:`COMPILE_GRACE_S`, since a compile
+  stops the loop for seconds); a replica that crashes
   (:class:`~repro.serve.ReplicaKilled`) or goes silent (missed heartbeats
   — the pause/wedge failure mode) is drained: taken out of placement,
   hard-killed, its worker joined (so its DMA streams are joined and no
@@ -51,6 +53,7 @@ import random
 import threading
 import time
 
+import jax
 import numpy as np
 
 from ..core import lockcheck
@@ -64,6 +67,11 @@ from .engine import (DONE, Engine, MigrationRefused, MigrationTicket,
 __all__ = ["Router", "RouterStats", "PLACEMENT_POLICY_NAMES",
            "PlacementPolicy", "get_placement",
            "encode_ticket", "decode_ticket"]
+
+# a replica compiling a model program for a new shape does not beat until
+# the compile ends (seconds at full size): it is declared dead only after
+# this much silence on top of the heartbeat timeout
+COMPILE_GRACE_S = 300.0
 
 
 # --------------------------------------------------------------------------
@@ -336,15 +344,22 @@ class Router:
         self._stop = threading.Event()
         self._error: BaseException | None = None
         self.replicas: list[_Replica] = []
+        # one accelerator per replica, round-robin over what this process
+        # sees: four replicas on a four-chip host each own a chip
+        devices = jax.devices()
         for i, name in enumerate(self.topology.replica_names):
             pool = (HostPool(self.topology.host_bytes_per_replica)
                     if self.topology.host_bytes_per_replica else None)
-            eng = Engine(model, params, cfg, pool=pool, name=name)
+            eng = Engine(model, params, cfg, pool=pool, name=name,
+                         device=devices[i % len(devices)])
             # each run-loop iteration beats the replica's heartbeat OFF the
             # engine lock; a wedged/paused loop stops beating and the
             # monitor drains it
             eng.on_step = (lambda _eng, _name=name:
                            self.heartbeat.beat(_name))
+            # a compile silences the loop for a bounded time: announce it
+            eng.on_compile = (lambda _eng, _name=name: self.heartbeat.beat(
+                _name, grace_s=COMPILE_GRACE_S))
             self.replicas.append(_Replica(i, name, eng, pool))
         self.nic.start()
         for rep in self.replicas:

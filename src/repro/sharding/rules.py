@@ -36,10 +36,7 @@ def constrain(x, *spec, require: str | None = None):
     UNCONSTRAINED — a constraint whose interesting axis was dropped would
     otherwise pin the tensor to replication, which is far worse than letting
     GSPMD choose (learned the hard way: §Perf iteration B2a)."""
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except AttributeError:       # jax < 0.5: no abstract-mesh API → un-meshed
-        return x
+    m = jax.sharding.get_abstract_mesh()
     if m is None or not getattr(m, "axis_names", ()):
         return x
     axes = set(m.axis_names)

@@ -383,11 +383,13 @@ def test_migration_roundtrip_through_disk_tier():
             assert store.disk.write_bytes > 0
             assert all(store.tier_of((want.rid, b)) == "disk"
                        for b in range(len(want.blocks)))
-            # the disk tier restores extended dtypes (bfloat16) as raw
-            # void words; relabel from the known spec before shipping,
-            # exactly as Engine._warm_payload_locked does at export
-            peeked = [_relabel(store.peek_offload((want.rid, b)), orig)
-                      for b, orig in enumerate(originals)]
+            # the disk tier reads extended dtypes (bfloat16) back as
+            # themselves, so the peeked blocks ship as they are
+            peeked = [store.peek_offload((want.rid, b))
+                      for b in range(len(originals))]
+            for got_blk, orig in zip(peeked, originals):
+                assert {k: v.dtype for k, v in got_blk.items()} == \
+                    {k: v.dtype for k, v in orig.items()}
             shipped = dataclasses_replace_blocks(want, peeked)
             assert all(b is not None for b in shipped.blocks)
             got = decode_ticket(encode_ticket(shipped))
@@ -400,20 +402,6 @@ def test_migration_roundtrip_through_disk_tier():
 def dataclasses_replace_blocks(t, blocks):
     import dataclasses as _dc
     return _dc.replace(t, blocks=blocks)
-
-
-def _relabel(block, reference):
-    """View void-typed disk reads back to their true dtypes (a relabel,
-    never a cast — the bytes are already exact)."""
-    out = {}
-    for k, v in block.items():
-        arr = np.asarray(v)
-        want = np.asarray(reference[k]).dtype
-        if arr.dtype != want and arr.dtype.kind == "V" \
-                and arr.dtype.itemsize == want.itemsize:
-            arr = arr.view(want)
-        out[k] = arr
-    return out
 
 
 # ------------------------------------------------------------- slow lane
@@ -469,8 +457,8 @@ def test_fuzz_hypothesis_migration_codec():
                     store.put_offload((want.rid, i), blk)
                     store.spill((want.rid, i))
                 shipped = dataclasses_replace_blocks(
-                    want, [_relabel(store.peek_offload((want.rid, b)), o)
-                           for b, o in enumerate(originals)])
+                    want, [store.peek_offload((want.rid, b))
+                           for b in range(len(originals))])
                 got = decode_ticket(encode_ticket(shipped))
                 _assert_ticket_bit_exact(
                     got, dataclasses_replace_blocks(want, originals))

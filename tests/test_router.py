@@ -161,6 +161,26 @@ def test_no_fault_fleet_matches_oracle(lm):
     assert_no_fleet_threads()
 
 
+def test_replicas_take_devices_round_robin(lm):
+    """Replica i owns ``jax.devices()[i % n]``: its params and its KV cache
+    live there (on a four-chip host, one chip per replica)."""
+    model, params = lm
+    devices = jax.devices()
+    topo = make_fleet_topology(3, heartbeat_timeout_s=60.0)
+    with Router(model, params, fleet_cfg(), topology=topo,
+                placement="least-loaded") as router:
+        rids = [router.submit(p, max_new=2)
+                for p in make_prompts(model, 3, seed=3)]
+        router.wait(rids, timeout=300)
+        for i, rep in enumerate(router.replicas):
+            want = {devices[i % len(devices)]}
+            assert {rep.engine.device} == want
+            assert rep.engine.kv is not None        # one request each
+            for tree in (rep.engine.params, rep.engine.kv.cache):
+                assert all(a.devices() == want for a in jax.tree.leaves(tree))
+    assert_no_fleet_threads()
+
+
 def test_paused_replica_detected_and_drained(lm):
     """The silent-wedge failure mode: a replica that stops beating without
     crashing (``pause()``) must be drained exactly like a crash — detected
@@ -196,6 +216,38 @@ def test_paused_replica_detected_and_drained(lm):
         assert not victim.alive and victim.closed
     assert outs == oracle(lm, prompts, rids, max_new=10)
     assert summ["replicas_killed"] == 1
+    assert_no_fleet_threads()
+
+
+def test_compile_longer_than_heartbeat_timeout_drains_nothing(
+        lm, monkeypatch):
+    """A full-size compile silences a replica's run loop for seconds. The
+    engine announces each compile, so a first compile three times the
+    heartbeat timeout is not taken for a wedge: nothing drains and the
+    tokens stay oracle-exact."""
+    model, params = lm
+    timeout = 1.0
+    compile_ = Engine._compile
+    slowed: set = set()
+
+    def slow_first_compile(self, fn, args):
+        if self.name not in slowed:
+            slowed.add(self.name)
+            time.sleep(3 * timeout)
+        return compile_(self, fn, args)
+
+    monkeypatch.setattr(Engine, "_compile", slow_first_compile)
+    topo = FleetTopology(n_replicas=2, heartbeat_timeout_s=timeout)
+    prompts = make_prompts(model, 4, seed=4)
+    with Router(model, params, fleet_cfg(), topology=topo,
+                placement="least-loaded") as router:
+        rids = [router.submit(p, max_new=4) for p in prompts]
+        router.wait(rids, timeout=300)
+        outs = [router.result(r) for r in rids]
+        summ = router.summary()
+    assert slowed == {"replica-0", "replica-1"}
+    assert summ["replicas_killed"] == 0 and summ["reprefills"] == 0
+    assert outs == oracle(lm, prompts, rids, max_new=4)
     assert_no_fleet_threads()
 
 

@@ -295,6 +295,26 @@ def test_pooled_engine_matches_oracle_and_bounds_pool(lm, arb):
             assert eng.stats.lease_deferrals > 0
 
 
+def test_pool_drains_when_run_ends_with_mirrors_queued(lm):
+    """Eager mirrors still queued on the d2h stream when the last request
+    finishes are abandoned at stream shutdown. Their reservations must go
+    back to the pool, or the kv lease stays charged after the run."""
+    from repro.core import HostPool
+    model, params = lm
+    prompt = list(range(1, 25))
+    blk = PagedKVCache(model, 1, 64, block_size=8).block_nbytes
+    pool = HostPool(16 * blk)
+    cfg = ServeConfig(max_len=64, batch_buckets=(1,), block_size=8,
+                      offload=True, hot_window=0, offload_fraction=1.0,
+                      d2h_bw=blk / 0.2)          # 0.2 s per mirrored block
+    with Engine(model, params, cfg, pool=pool) as eng:
+        out = eng.generate([prompt], max_new=4)
+        assert out == oracle(lm, [prompt], max_new=4, max_len=64)
+        # three cold blocks were queued; the run ended before all landed
+        assert eng.stats.offload_bytes < 3 * blk
+        assert pool.snapshot()["leases"]["kv"]["used"] == 0
+
+
 def test_runtime_and_serving_share_one_arbitrated_pool(lm):
     """The headline scenario: a MEMGRAPH plan's offload traffic and the
     serving engine's KV mirror running *concurrently* against ONE
@@ -404,3 +424,56 @@ def test_bytearena_drop_invalidates():
     arena.drop(loc)
     with pytest.raises(RaceError):
         arena.read(loc)
+
+
+# ------------------------------------------------------------ chip smoke
+def _chip_smoke():
+    """``chip_smoke.py`` lives at the repository root, outside the package."""
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_serving_phase_on_cpu():
+    """The chip smoke's serving phase at the reduced Qwen2.5 config: every
+    tier carries traffic, and in float32 on the CPU every request matches
+    the oracle exactly (no token departs, every logit row within bound)."""
+    smoke = _chip_smoke()
+    r = smoke.serve_phase(reduced(get_arch("qwen2.5-3b")), seed=0)
+    assert r["failures"] == []
+    for key in ("swaps", "offload_bytes", "reload_bytes", "disk_load_bytes"):
+        assert r[key] > 0, key
+    assert r["completed"] == r["oracle_matched"] == "12/12"
+    assert r["positions_checked"] == 12 * smoke.MAX_NEW
+    assert r["token_departures"] == []
+
+
+def test_chip_smoke_oracle_comparison_allows_only_near_ties():
+    """Every position is compared with the oracle teacher-forced on the
+    engine's tokens: a token departure passes only while the engine's
+    whole logit row stays within ``TOL`` of the row's largest logit, and
+    an earlier departure excuses nothing after it."""
+    smoke = _chip_smoke()
+    tol = 4.0 * smoke.TOL                    # absolute, at a row max of 4
+    o1 = np.zeros(8, np.float32)
+    o1[3], o1[5] = 4.0, 4.0 - tol / 2        # a near-tie
+    o2 = np.zeros(8, np.float32)
+    o2[1] = 2.0
+    oracle = [([3, 1], [o1, o2])]
+    r = smoke.compare_with_oracle([[3, 1]], {0: [o1, o2]}, oracle)
+    assert (r["matched"], r["departures"], r["failures"]) == (1, [], [])
+    flip = o1.copy()
+    flip[5] = 4.0 + tol / 4                  # the tie flips, within bound
+    r = smoke.compare_with_oracle([[5, 1]], {0: [flip, o2]}, oracle)
+    assert (r["matched"], r["failures"]) == (0, [])
+    assert [d["pos"] for d in r["departures"]] == [0]
+    far = o2.copy()
+    far[6] = 2.5                             # beyond bound after the tie
+    r = smoke.compare_with_oracle([[5, 6]], {0: [flip, far]}, oracle)
+    assert len(r["failures"]) == 1 and "pos 1" in r["failures"][0]
+    r = smoke.compare_with_oracle([[3]], {0: [o1]}, oracle)   # too short
+    assert r["failures"]

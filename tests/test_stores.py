@@ -47,6 +47,19 @@ class TestDiskStore:
         assert "a" not in ds and ds.resident_bytes < ds.read_bytes
         ds.close()
 
+    def test_extended_dtypes_read_back_as_themselves(self, tmp_path):
+        """bfloat16 KV blocks (what a TPU cache holds) come back from the
+        log as bfloat16, bit-exact, not as anonymous void words."""
+        import ml_dtypes
+        ds = DiskStore(tmp_path)
+        k = np.linspace(-3, 3, 24, dtype=np.float32).astype(
+            ml_dtypes.bfloat16).reshape(2, 12)
+        ds.put(("r", 0), {"k": k, "v": k[::-1].copy()})
+        got = ds.get(("r", 0))
+        assert got["k"].dtype == k.dtype and got["v"].dtype == k.dtype
+        assert got["k"].tobytes() == k.tobytes()
+        ds.close()
+
     def test_close_removes_private_dir(self):
         ds = DiskStore()
         ds.put("x", np.ones(4))

@@ -32,6 +32,17 @@ class TestHeartbeatDetection:
         assert hb.dead_workers(now=5.5) == []
         assert hb.dead_workers(now=6.5) == ["w"]
 
+    def test_grace_extends_one_silence_and_stays_bounded(self):
+        """A beat with ``grace_s`` (a compile about to start) holds off
+        detection for the grace on top of the timeout, and no longer; the
+        next plain beat restores the plain timeout."""
+        hb = Heartbeat(timeout_s=2.0)
+        hb.beat("w", now=100.0, grace_s=30.0)
+        assert hb.dead_workers(now=131.9) == []
+        assert hb.dead_workers(now=132.01) == ["w"]
+        hb.beat("w", now=140.0)
+        assert hb.dead_workers(now=142.01) == ["w"]
+
     def test_forget_retires_a_drained_worker(self):
         """A drained replica must stop reporting dead on every later poll
         — otherwise the fleet monitor re-drains a corpse forever."""

@@ -58,6 +58,11 @@ def _run(model, params, prompts, cfg: ServeConfig, max_new: int):
     return out, eng.stats, wall
 
 
+def tok_s(st) -> float:
+    """Tokens emitted over the run loop's wall time (its phases' sum)."""
+    return st.tokens / max(st.loop_time, 1e-9)
+
+
 def run(quick: bool = True) -> None:
     rng = np.random.default_rng(0)
     model = build_model(ARCH)
@@ -82,15 +87,15 @@ def run(quick: bool = True) -> None:
     for _ in range(2 if quick else 3):
         for name, cfg in grid.items():
             out, st, _ = _run(model, params, prompts, cfg, max_new)
-            if name not in best or st.decode_tok_s > best[name][1].decode_tok_s:
+            if name not in best or tok_s(st) > tok_s(best[name][1]):
                 best[name] = (out, st)
     ref_out, ref_stats = best["no_offload"]
-    ref_rate = ref_stats.decode_tok_s
+    ref_rate = tok_s(ref_stats)
     emit("serving/decode/no_offload",
          1e6 / max(ref_rate, 1e-9), f"tok_s={ref_rate:.1f}")
     for name in ("offload_frac0.6", "offload_frac1"):
         out, st = best[name]
-        rate = st.decode_tok_s
+        rate = tok_s(st)
         ratio = ref_rate / max(rate, 1e-9)
         emit(f"serving/decode/{name}",
              1e6 / max(rate, 1e-9),

@@ -48,7 +48,8 @@ def main() -> None:
     st = eng.stats
     print(f"\nmatches unbatched oracle: {ok}")
     print(f"decode steps {st.decode_steps}, tokens {st.tokens} "
-          f"({st.decode_tok_s:.0f} tok/s), swaps {st.swaps}")
+          f"({st.tokens / st.loop_time:.0f} tok/s over the run loop's "
+          f"{st.loop_time:.2f} s), swaps {st.swaps}")
     print(f"d2h offload traffic {st.offload_bytes / 2**20:.2f} MiB "
           f"({st.offloaded_fraction:.0%} of the KV bytes produced — "
           f"swap thrash can push this past 100%), h2d reload traffic "

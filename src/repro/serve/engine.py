@@ -67,6 +67,7 @@ engine configuration must match.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import random
 import threading
@@ -85,8 +86,9 @@ from ..core.liveness import (LeaseSpec, LivenessCertificate,
 from ..core.memgraph import MemGraph
 from ..core.stores import HostStore, TieredStore
 from .kv_cache import PagedKVCache
+from .spans import Span
 
-__all__ = ["ServeConfig", "Engine", "Request", "ServeStats",
+__all__ = ["ServeConfig", "Engine", "Request", "ServeStats", "LOOP_PHASES",
            "ReloadPolicy", "RELOAD_POLICY_NAMES", "get_reload_policy",
            "ReplicaKilled", "MigrationRefused", "MigrationTicket",
            "naive_generate"]
@@ -200,6 +202,9 @@ class Request:
     # the ticket, so a resumed request keeps its original latency history.
     t_submit: float = 0.0
     t_first: float = 0.0
+    # lifecycle stamps (time.monotonic()): preempted, slot granted back
+    t_swap: float = 0.0
+    t_grant: float = 0.0
 
 
 @dataclasses.dataclass
@@ -227,17 +232,50 @@ class ServeStats:
     migrations_in: int = 0            # warm tickets imported (router fleet)
     migrations_out: int = 0           # warm tickets exported off this
     #                                   replica (drain + live rebalance)
+    # ---- run-loop phases (LOOP_PHASES): wall seconds of the loop
+    # thread, each the counter of one serve.* span (spans.py). Every
+    # instant of run() lies in exactly one; decode_time, prefill_time
+    # and stall_time (above) are three of them.
+    hook_time: float = 0.0            # on_step and the pause wait
+    lock_wait_time: float = 0.0       # acquiring the engine lock
+    events_time: float = 0.0          # completion events, kill checks
+    restore_time: float = 0.0         # restore_slot: a resume's h2d copy
+    restores: int = 0
+    drop_time: float = 0.0            # drop_slot of a swapped-out slot
+    admit_time: float = 0.0           # admission planning, prefill inputs
+    scatter_time: float = 0.0         # prefill K/V scatter, first tokens
+    schedule_time: float = 0.0        # offload/spill/prefetch/preempt
+    #                                   passes and the decode inputs
+    emit_time: float = 0.0            # new cache handed over, sampling
+    # ---- DMA streams: wall seconds and bytes of each stream's thread
+    d2h_copy_time: float = 0.0        # read_block (device -> host copy)
+    d2h_copy_bytes: int = 0
+    d2h_store_time: float = 0.0       # put_offload under the engine lock
+    disk_io_time: float = 0.0         # spill, load and prefetch file I/O
+    d2h_wire_time: float = 0.0        # simulated wire sleeps, per stream
+    h2d_wire_time: float = 0.0
+    disk_wire_time: float = 0.0
+    # ---- request lifecycle (time.monotonic() stamps)
+    queue_time: float = 0.0           # submission -> first admission
+    admissions: int = 0
+    swap_stall_time: float = 0.0      # preemption -> running again
+    resumes: int = 0
+    swapped_time: float = 0.0         # preemption -> slot granted
+    reloading_time: float = 0.0       # slot granted -> running again
 
     @property
     def offloaded_fraction(self) -> float:
         return self.offload_bytes / max(self.kv_bytes_written, 1)
 
     @property
-    def decode_tok_s(self) -> float:
-        """Decode-step throughput: first tokens (sampled from prefill
-        logits during prefill_time) are excluded from the numerator."""
-        return self.decode_tokens / max(self.decode_time + self.stall_time,
-                                        1e-9)
+    def loop_time(self) -> float:
+        """Wall seconds of the run loop: the sum of its phases."""
+        return sum(getattr(self, k) for k in LOOP_PHASES)
+
+
+LOOP_PHASES = ("hook_time", "lock_wait_time", "events_time", "restore_time",
+               "drop_time", "admit_time", "prefill_time", "scatter_time",
+               "schedule_time", "decode_time", "emit_time", "stall_time")
 
 
 # --------------------------------------------------------------------------
@@ -356,15 +394,17 @@ class _DmaStream(threading.Thread):
 
     Pops the best-ranked pending transfer (policy choice = the runtime's
     nondeterministic dispatch), sleeps the simulated wire time *off* the
-    engine lock so transfers overlap under decode, then runs the service
+    engine lock so transfers overlap under decode (the ``serve.dma.wire``
+    span, into ``stats.<kind>_wire_time``), then runs the service
     callback (a short memcpy / completion event under the lock)."""
 
     def __init__(self, kind: str, bw: float, latency: float,
                  policy: ReloadPolicy, service, lock: threading.Lock, *,
-                 fuse: bool = False, max_fuse: int = 8,
+                 stats: ServeStats, fuse: bool = False, max_fuse: int = 8,
                  on_batch=None) -> None:
         super().__init__(name=f"serve-dma-{kind}")
         self.kind = kind
+        self.stats = stats
         self.bw = bw
         self.latency = latency
         self.policy = policy
@@ -409,8 +449,14 @@ class _DmaStream(threading.Thread):
                         self.on_batch(len(batch))
                 # one submission for the run: a single fixed launch
                 # latency plus every member's wire bytes
-                wire = self.latency + sum(t.nbytes for t in batch) / self.bw
-                time.sleep(wire)
+                nbytes = sum(t.nbytes for t in batch)
+                wire = self.latency + nbytes / self.bw
+                if wire > 0:
+                    with Span(self.stats, f"{self.kind}_wire_time",
+                              "serve.dma.wire", rid=batch[0].rid,
+                              blk=batch[0].blk, nbytes=nbytes,
+                              transfers=len(batch)):
+                        time.sleep(wire)
                 for tr in batch:
                     self.service(tr)
         except BaseException as e:       # surface in the engine loop — a
@@ -597,6 +643,11 @@ class Engine:
         # path that is NOT a crash.
         self._pause_evt = threading.Event()
         self._pause_evt.set()
+        # the run-loop phase that is open, as (span name, perf_counter()
+        # at its start); None outside run(). A plain attribute, written by
+        # the loop thread only: the router reads it when it drains a
+        # silent replica.
+        self.phase: tuple[str, float] | None = None
 
     # ---------------------------------------------- pool lease bookkeeping
     def pool_model(self) -> PoolConfig:
@@ -951,7 +1002,12 @@ class Engine:
         Returns once the live set is observed empty under the lock: a
         request submitted concurrently after that instant waits for the
         next ``run()`` — a long-lived online service keeps a run loop (or
-        re-invokes ``run()`` after submitting)."""
+        re-invokes ``run()`` after submitting).
+
+        Each iteration is a sequence of loop phases, ``serve.*`` spans that
+        never nest (``LOOP_PHASES``): hooks, lock waits, events (with
+        ``restore_slot`` and ``drop_slot``), admission, prefill and its
+        scatter, the scheduling passes, then decode and emit or a stall."""
         if seed is not None:
             self._seed = seed
         cfg = self.cfg
@@ -960,8 +1016,8 @@ class Engine:
         def _on_batch(n: int) -> None:      # lock held (stream cond)
             self.stats.fused_dma_batches += 1
 
-        fuse_kw = dict(fuse=cfg.fuse_dma, max_fuse=cfg.max_fuse_dma,
-                       on_batch=_on_batch)
+        fuse_kw = dict(stats=self.stats, fuse=cfg.fuse_dma,
+                       max_fuse=cfg.max_fuse_dma, on_batch=_on_batch)
         self._d2h = _DmaStream(D2H, cfg.d2h_bw, cfg.dma_latency, pol,
                                self._service_d2h, self._lock, **fuse_kw)
         self._h2d = _DmaStream(H2D, cfg.h2d_bw, cfg.dma_latency, pol,
@@ -978,38 +1034,50 @@ class Engine:
             stream.start()
         try:
             while True:
-                if self.on_step is not None:
-                    # off the lock: the heartbeat table is a leaf lock and
-                    # the callback must never nest inside the engine lock
-                    self.on_step(self)
-                self._pause_evt.wait()
-                with self._lock:
-                    if self._killed or (
-                            self.fault_after_steps is not None
-                            and self.stats.decode_steps
-                            >= self.fault_after_steps):
-                        raise ReplicaKilled(
-                            f"replica {self.name!r} hard-killed after "
-                            f"{self.stats.decode_steps} decode steps")
-                    for stream in streams:
-                        if stream.error is not None:
-                            raise stream.error
+                with self._loop_span("serve.loop.hooks", "hook_time"):
+                    if self.on_step is not None:
+                        # off the lock: the heartbeat table is a leaf lock
+                        # and the callback must never nest inside the
+                        # engine lock
+                        self.on_step(self)
+                    self._pause_evt.wait()
+                with self._loop_lock():
+                    with self._loop_span("serve.loop.events", "events_time"):
+                        if self._killed or (
+                                self.fault_after_steps is not None
+                                and self.stats.decode_steps
+                                >= self.fault_after_steps):
+                            raise ReplicaKilled(
+                                f"replica {self.name!r} hard-killed after "
+                                f"{self.stats.decode_steps} decode steps")
+                        for stream in streams:
+                            if stream.error is not None:
+                                raise stream.error
                     self._apply_events_locked()
-                    admits = self._plan_admissions_locked()
+                    with self._loop_span("serve.loop.admit", "admit_time"):
+                        admits = self._plan_admissions_locked()
                 if admits:
                     self._prefill_admit(admits)
-                with self._lock:
-                    self._schedule_offload_locked()
-                    self._schedule_spill_locked()
-                    self._schedule_prefetch_locked()
-                    self._schedule_preempt_locked()
-                    active = [(s, r) for s, r in enumerate(self._slots)
-                              if r is not None
-                              and self.reqs[r].state == RUNNING]
-                    if not self._live:     # atomic with submit()'s mutation
-                        break
+                with self._loop_lock():
+                    with self._loop_span("serve.loop.schedule",
+                                         "schedule_time"):
+                        self._schedule_offload_locked()
+                        self._schedule_spill_locked()
+                        self._schedule_prefetch_locked()
+                        self._schedule_preempt_locked()
+                        active = [(s, r) for s, r in enumerate(self._slots)
+                                  if r is not None
+                                  and self.reqs[r].state == RUNNING]
+                        if not self._live:  # atomic with submit()'s mutation
+                            break
+                        if active:
+                            inputs = self._decode_inputs_locked(active)
                 if active:
-                    self._decode_once(active)
+                    self._decode_once(active, inputs)
+                    # the pre-step cache must not outlive its step: held
+                    # here, it would double the cache on the device
+                    # through the next iteration's paging and prefill
+                    del inputs
                 else:
                     self._stall_wait()
         finally:
@@ -1028,7 +1096,25 @@ class Engine:
                 self._prefetch_inflight.clear()
             for stream in streams:
                 stream.join()
+            self.phase = None
         return self.stats
+
+    def _loop_span(self, name: str, counter: str, **meta) -> Span:
+        """A run-loop phase: a span whose name the engine keeps open in
+        ``self.phase``. Loop phases never nest, so every instant of the
+        loop lies in exactly one (``ServeStats.loop_time``)."""
+        return Span(self.stats, counter, name, track=self, **meta)
+
+    @contextlib.contextmanager
+    def _loop_lock(self):
+        """The engine lock, as the run loop takes it: the wait for it is
+        the ``serve.loop.lock_wait`` phase."""
+        with self._loop_span("serve.loop.lock_wait", "lock_wait_time"):
+            self._lock.acquire()
+        try:
+            yield
+        finally:
+            self._lock.release()
 
     # -------------------------------------------------- DMA service hooks
     # (run on stream threads after the simulated wire time; they only read
@@ -1051,8 +1137,12 @@ class Engine:
         # decode like a real copy engine; the slot cannot be reassigned
         # while this block is in flight (swap-out completes only once
         # `inflight` drains), so only completion can invalidate it
-        data = self.kv.read_block(slot, tr.blk, cache=snapshot)
-        with self._lock:
+        meta = dict(rid=tr.rid, blk=tr.blk, nbytes=tr.nbytes)
+        with Span(self.stats, "d2h_copy_time", "serve.d2h.copy", **meta):
+            data = self.kv.read_block(slot, tr.blk, cache=snapshot)
+        self.stats.d2h_copy_bytes += tr.nbytes
+        with self._lock, Span(self.stats, "d2h_store_time",
+                              "serve.d2h.store", **meta):
             req.inflight.discard(tr.blk)
             if req.state != DONE and req.slot == slot:
                 self.host.put_offload((tr.rid, tr.blk), data)
@@ -1069,6 +1159,8 @@ class Engine:
             self._wake.notify_all()
 
     def _service_h2d(self, tr: _Transfer) -> None:
+        # only a host reference is fetched here: the host-to-device copy
+        # of a resume happens in restore_slot, on the run loop
         data = self.host.get_offload((tr.rid, tr.blk))
         with self._lock:
             self.stats.reload_bytes += tr.nbytes
@@ -1089,6 +1181,7 @@ class Engine:
         block mid-spill and drag the disk read onto the h2d lane via
         read-through. One block's write is cheap; the invariant is not."""
         key = (tr.rid, tr.blk)
+        meta = dict(rid=tr.rid, blk=tr.blk, nbytes=tr.nbytes)
         if tr.disk_op == "prefetch":
             # predictive staging for a request still waiting in the swapped
             # queue: bring the blob host-side so its eventual resume is a
@@ -1099,9 +1192,11 @@ class Engine:
             # block the reactive path already staged (and counted) is seen
             # host-resident and not double-counted.
             try:
-                staged = self.host.tier_of(key) == "disk"
-                if staged:
-                    self.host.load(key)
+                with Span(self.stats, "disk_io_time", "serve.disk.prefetch",
+                          **meta):
+                    staged = self.host.tier_of(key) == "disk"
+                    if staged:
+                        self.host.load(key)
             except KeyError:
                 staged = False
             with self._lock:
@@ -1153,17 +1248,20 @@ class Engine:
                     # and the spill (which would push the disk read onto
                     # the h2d lane via read-through). The write itself is
                     # one small block; the wire time was slept off-lock.
-                    if self._pool is not None:
-                        # mark this thread as the kv lease's revocation
-                        # drain (assumption A2): the spill may only
-                        # release — a charge against any undeclared lease
-                        # in here would be a blocking edge the liveness
-                        # model never saw, and the pool rejects it loudly
-                        with self._pool.draining(self._kv_lease):
-                            self.stats.disk_spill_bytes += \
-                                self.host.spill(key)
-                    else:
-                        self.stats.disk_spill_bytes += self.host.spill(key)
+                    with Span(self.stats, "disk_io_time",
+                              "serve.disk.spill", under_lock=1, **meta):
+                        if self._pool is not None:
+                            # mark this thread as the kv lease's revocation
+                            # drain (assumption A2): the spill may only
+                            # release — a charge against any undeclared
+                            # lease in here would be a blocking edge the
+                            # liveness model never saw, and the pool
+                            # rejects it loudly
+                            with self._pool.draining(self._kv_lease):
+                                spilled = self.host.spill(key)
+                        else:
+                            spilled = self.host.spill(key)
+                    self.stats.disk_spill_bytes += spilled
                     # the host copy moved down a tier: its reservation is
                     # what the arbiter has been waiting for
                     self._release_key_locked(key)
@@ -1171,7 +1269,8 @@ class Engine:
             return
         # load: read-through staging is idempotent, so a racy spill/reload
         # interleaving can only change timing, never bytes
-        self.host.load(key)
+        with Span(self.stats, "disk_io_time", "serve.disk.load", **meta):
+            self.host.load(key)
         with self._lock:
             self.stats.disk_load_bytes += tr.nbytes
             req = self.reqs.get(tr.rid)
@@ -1184,41 +1283,64 @@ class Engine:
 
     # ------------------------------------------------------ event applies
     def _apply_events_locked(self) -> None:
-        for ev in self._events:
-            if ev[0] == "reload":
-                _, rid, blk, data = ev
-                req = self.reqs.get(rid)
-                if req is None or req.state != RELOADING:
-                    continue
-                req.reload_data[blk] = data
-                req.pending_reload.discard(blk)
-                if not req.pending_reload:
-                    # one per-leaf scatter for the whole resume, not one
-                    # full-cache copy per block
-                    self.kv.restore_slot(
-                        req.slot, [req.reload_data[b]
-                                   for b in sorted(req.reload_data)])
-                    req.reload_data.clear()
-                    req.state = RUNNING
-                    req.quantum = 0
-                    # the tail block keeps growing after resume: its host
-                    # copy is stale from now on and must re-offload (every
-                    # cold block's copy stays valid — reuse_host_copy)
-                    if req.pos % self.cfg.block_size:
-                        tail = req.pos // self.cfg.block_size
-                        req.mirrored.discard(tail)
-                        self.host.pop_offload((rid, tail))
-                        self._release_key_locked((rid, tail))
-            elif ev[0] == "swap-done":
-                req = self.reqs.get(ev[1])
-                if req is None or req.state != SWAPPING:
-                    continue
-                self.kv.drop_slot(req.slot)
-                self._slots[req.slot] = None
-                req.slot = -1
-                req.state = SWAPPED
-                self._swapped.append(req.rid)
-        self._events.clear()
+        """Apply the DMA streams' completion events. The device work they
+        call for (a resume's ``restore_slot``, a swap-out's ``drop_slot``)
+        runs after the bookkeeping, each in its own loop phase; the slots
+        involved are distinct, so the order of their cache updates does
+        not matter."""
+        restores: list[tuple[Request, list[dict]]] = []
+        drops: list[int] = []
+        with self._loop_span("serve.loop.events", "events_time"):
+            for ev in self._events:
+                if ev[0] == "reload":
+                    _, rid, blk, data = ev
+                    req = self.reqs.get(rid)
+                    if req is None or req.state != RELOADING:
+                        continue
+                    req.reload_data[blk] = data
+                    req.pending_reload.discard(blk)
+                    if not req.pending_reload:
+                        # one per-leaf scatter for the whole resume, not
+                        # one full-cache copy per block
+                        restores.append((req, [req.reload_data[b] for b in
+                                               sorted(req.reload_data)]))
+                        req.reload_data.clear()
+                        req.state = RUNNING
+                        req.quantum = 0
+                elif ev[0] == "swap-done":
+                    req = self.reqs.get(ev[1])
+                    if req is None or req.state != SWAPPING:
+                        continue
+                    drops.append(req.slot)
+                    self._slots[req.slot] = None
+                    req.slot = -1
+                    req.state = SWAPPED
+                    self._swapped.append(req.rid)
+            self._events.clear()
+        for req, blocks in restores:
+            with self._loop_span("serve.kv.restore_slot", "restore_time",
+                                 rid=req.rid, blocks=len(blocks)):
+                self.kv.restore_slot(req.slot, blocks)
+                self.stats.restores += 1
+                # the tail block keeps growing after resume: its host copy
+                # is stale from now on and must re-offload (every cold
+                # block's copy stays valid — reuse_host_copy). Popped only
+                # once restore_slot has read it.
+                if req.pos % self.cfg.block_size:
+                    tail = req.pos // self.cfg.block_size
+                    req.mirrored.discard(tail)
+                    self.host.pop_offload((req.rid, tail))
+                    self._release_key_locked((req.rid, tail))
+                if req.t_swap:
+                    now = time.monotonic()
+                    self.stats.resumes += 1
+                    self.stats.swap_stall_time += now - req.t_swap
+                    self.stats.swapped_time += req.t_grant - req.t_swap
+                    self.stats.reloading_time += now - req.t_grant
+                    req.t_swap = 0.0
+        for slot in drops:
+            with self._loop_span("serve.kv.drop_slot", "drop_time"):
+                self.kv.drop_slot(slot)
 
     # ----------------------------------------------------- admission path
     def _bucket_for(self, n: int) -> int:
@@ -1251,12 +1373,15 @@ class Engine:
         # reclaim its slot ahead of them (a production engine would add an
         # aging term here to bound swapped-out residence)
         admits: list[tuple[int, int]] = []
+        now = time.monotonic()
         while free and self._queue:
             rid = self._queue.pop(0)
             slot = free.pop(0)
             self._slots[slot] = rid
             self.reqs[rid].slot = slot
             admits.append((slot, rid))
+            self.stats.admissions += 1
+            self.stats.queue_time += now - self.reqs[rid].t_submit
 
         # swap-ins: host-resident blocks reload through the h2d stream;
         # disk-resident blocks take the pipelined two-hop chain (disk
@@ -1304,6 +1429,7 @@ class Engine:
             self._slots[slot] = rid
             req.slot = slot
             req.state = RELOADING
+            req.t_grant = now
             req.pending_reload = set(blocks)
             for blk in blocks:
                 if (rid, blk) in self._prefetch_inflight:
@@ -1350,22 +1476,24 @@ class Engine:
         (bucket, block-aligned-length) static shape), then scatter the K/V
         into the admitted slots and sample each request's first token."""
         cfg = self.cfg
-        reqs = [self.reqs[rid] for _, rid in admits]
-        max_p = max(len(r.prompt) for r in reqs)
-        s_pad = min(-(-max_p // cfg.block_size) * cfg.block_size,
-                    cfg.max_len)
-        b_pad = self._bucket_for(len(reqs))
-        toks = np.zeros((b_pad, s_pad), np.int32)
-        lengths = np.ones((b_pad,), np.int32)
-        for i, r in enumerate(reqs):
-            toks[i, :len(r.prompt)] = r.prompt
-            lengths[i] = len(r.prompt)
-        t0 = time.perf_counter()
-        logits, kv = self._run_program(self._prefill, self.kv.put(toks),
-                                       self.kv.put(lengths))
-        logits_np = np.asarray(logits, np.float32)
-        self.stats.prefill_time += time.perf_counter() - t0
-        with self._lock:
+        with self._loop_span("serve.loop.admit", "admit_time"):
+            reqs = [self.reqs[rid] for _, rid in admits]
+            max_p = max(len(r.prompt) for r in reqs)
+            s_pad = min(-(-max_p // cfg.block_size) * cfg.block_size,
+                        cfg.max_len)
+            b_pad = self._bucket_for(len(reqs))
+            toks = np.zeros((b_pad, s_pad), np.int32)
+            lengths = np.ones((b_pad,), np.int32)
+            for i, r in enumerate(reqs):
+                toks[i, :len(r.prompt)] = r.prompt
+                lengths[i] = len(r.prompt)
+        with self._loop_span("serve.prefill", "prefill_time", rows=len(reqs),
+                             bucket=b_pad, padded_len=s_pad):
+            logits, kv = self._run_program(self._prefill, self.kv.put(toks),
+                                           self.kv.put(lengths))
+            logits_np = np.asarray(logits, np.float32)
+        with self._loop_lock(), self._loop_span("serve.kv.scatter_prefill",
+                                                "scatter_time"):
             rows = jax.tree.map(lambda a: a[:, :len(reqs)], kv)
             self.kv.scatter_prefill([slot for slot, _ in admits], rows)
             for i, (slot, rid) in enumerate(admits):
@@ -1590,6 +1718,7 @@ class Engine:
                         self._release_key_locked(key)
                     continue
             req.state = SWAPPING
+            req.t_swap = time.monotonic()
             self.stats.swaps += 1
             waiting -= 1
             for blk in pending:
@@ -1598,27 +1727,32 @@ class Engine:
                 self._events.append(("swap-done", rid))
 
     # -------------------------------------------------------------- decode
-    def _decode_once(self, active: list[tuple[int, int]]) -> None:
-        with self._lock:
-            self._idle_spins = 0               # decode is forward progress
-            bucket = self.kv.bucket
-            cache = self.kv.cache
-            toks = np.zeros((bucket, 1), np.int32)
-            lens = np.zeros((bucket,), np.int32)
-            mask = np.zeros((bucket,), bool)
-            for slot, rid in active:
-                req = self.reqs[rid]
-                toks[slot, 0] = req.last
-                lens[slot] = req.pos
-                mask[slot] = True
-        t0 = time.perf_counter()
-        put = self.kv.put
-        logits, new_cache = self._run_program(self._step, cache, put(toks),
-                                              put(lens), put(mask))
-        logits_np = np.asarray(logits, np.float32)
-        self.stats.decode_time += time.perf_counter() - t0
-        self.stats.decode_steps += 1
-        with self._lock:
+    def _decode_inputs_locked(self, active: list[tuple[int, int]]):
+        """The next decode step's cache and host inputs: the feed token,
+        cache length and live mask of every slot."""
+        self._idle_spins = 0                   # decode is forward progress
+        bucket = self.kv.bucket
+        toks = np.zeros((bucket, 1), np.int32)
+        lens = np.zeros((bucket,), np.int32)
+        mask = np.zeros((bucket,), bool)
+        for slot, rid in active:
+            req = self.reqs[rid]
+            toks[slot, 0] = req.last
+            lens[slot] = req.pos
+            mask[slot] = True
+        return self.kv.cache, toks, lens, mask
+
+    def _decode_once(self, active: list[tuple[int, int]], inputs) -> None:
+        cache, toks, lens, mask = inputs
+        with self._loop_span("serve.decode", "decode_time", rows=len(active),
+                             bucket=len(lens)):
+            put = self.kv.put
+            logits, new_cache = self._run_program(
+                self._step, cache, put(toks), put(lens), put(mask))
+            logits_np = np.asarray(logits, np.float32)
+        with self._loop_lock(), self._loop_span("serve.decode.emit",
+                                                "emit_time"):
+            self.stats.decode_steps += 1
             self.kv.cache = new_cache
             for slot, rid in active:
                 req = self.reqs[rid]
@@ -1630,8 +1764,7 @@ class Engine:
 
     def _stall_wait(self) -> None:
         """Nothing resident to decode: wait for a DMA completion event."""
-        t0 = time.perf_counter()
-        with self._wake:
+        with self._loop_span("serve.loop.stall", "stall_time"), self._wake:
             busy = (self._events or self._d2h.pending or self._h2d.pending
                     or (self._disk is not None and self._disk.pending)
                     or self._spill_inflight or self._prefetch_inflight
@@ -1676,7 +1809,6 @@ class Engine:
                         "consumer is releasing any — live waits-for "
                         f"graph: {waits}")
             self._wake.wait(timeout=0.1)
-        self.stats.stall_time += time.perf_counter() - t0
 
 
 # --------------------------------------------------------------------------

@@ -130,7 +130,10 @@ class PagedKVCache:
                      blocks: list[dict[str, np.ndarray]]) -> None:
         """Apply a resumed request's reloaded blocks 0..n-1 in ONE per-leaf
         scatter — block-wise application would copy every cache leaf once
-        per block."""
+        per block. This is where a resume's host-to-device copy happens
+        (the h2d stream only fetches host references): the host-side
+        concatenate, the ``device_put`` and the scatter's dispatch, timed
+        by the engine's ``serve.kv.restore_slot`` span."""
         span = len(blocks) * self.block_size
         self.cache = {
             k: leaf.at[:, slot, :span].set(self.put(

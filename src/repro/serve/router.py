@@ -49,6 +49,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import json
+import logging
 import random
 import threading
 import time
@@ -63,10 +64,13 @@ from ..ft.supervisor import Heartbeat
 from ..launch.mesh import FleetTopology
 from .engine import (DONE, Engine, MigrationRefused, MigrationTicket,
                      ReplicaKilled, ServeConfig)
+from .spans import Span
 
 __all__ = ["Router", "RouterStats", "PLACEMENT_POLICY_NAMES",
            "PlacementPolicy", "get_placement",
            "encode_ticket", "decode_ticket"]
+
+log = logging.getLogger(__name__)
 
 # a replica compiling a model program for a new shape does not beat until
 # the compile ends (seconds at full size): it is declared dead only after
@@ -307,7 +311,12 @@ class RouterStats:
     reprefills: int = 0          # cold fallbacks (device state lost)
     replicas_killed: int = 0
     drain_time: float = 0.0      # wall seconds spent draining dead replicas
+    #                              (the serve.router.drain span)
     ttft_p99: dict[str, float] = dataclasses.field(default_factory=dict)
+    # one record per drained replica: its name, the cause ("heartbeat" or
+    # the fault's repr), and for a heartbeat drain the engine loop phase
+    # that was open and for how many seconds it had been open
+    drains: list[dict] = dataclasses.field(default_factory=list)
 
 
 class Router:
@@ -502,40 +511,56 @@ class Router:
                            and (r.fault is not None or r.name in dead)]
             for rep in faulted:
                 try:
-                    self._drain_replica(rep)
+                    self._drain_replica(rep, silent=rep.fault is None)
                 except BaseException as e:   # noqa: BLE001
                     with self._lock:
-                        self._error = e
+                        # a crashed worker's own error names the cause;
+                        # a failed drain after it must not hide it
+                        if self._error is None:
+                            self._error = e
                     return
             with self._lock:
                 self._dispatch_locked()
 
-    def _drain_replica(self, rep: _Replica) -> None:
+    def _drain_replica(self, rep: _Replica, *, silent: bool = False) -> None:
         """The fault-tolerance path, in the one order that guarantees no
         double execution and no leaked threads: remove from placement →
         hard-kill (idempotent for an already-crashed loop) → resume (a
         paused loop must wake to observe the kill) → join the worker (its
         ``run()`` finally joins every DMA stream) → forget the heartbeat →
         checkpoint every live request → ship each over the NIC (warm
-        import, cold re-prefill fallback) → retire the replica's store."""
-        t0 = time.monotonic()
+        import, cold re-prefill fallback) → retire the replica's store.
+
+        ``silent``: drained for missed heartbeats. The engine loop phase
+        open at that moment, and for how long, goes into
+        ``RouterStats.drains``, the log and the span's metadata."""
+        phase = rep.engine.phase if silent else None
+        record = {"replica": rep.name,
+                  "cause": "heartbeat" if silent else repr(rep.fault),
+                  "phase": phase[0] if phase else None,
+                  "phase_s": time.perf_counter() - phase[1] if phase else 0.0}
         with self._lock:
             if not rep.alive:
                 return
             rep.alive = False
-        rep.engine.hard_kill()
-        rep.engine.resume()
-        if rep.thread is not None:
-            rep.thread.join()
-        self.heartbeat.forget(rep.name)
-        tickets = rep.engine.drain_tickets()
-        for ticket in tickets:
-            self._ship(ticket)
-        rep.engine.close()
-        rep.closed = True
-        with self._lock:
-            self.stats.replicas_killed += 1
-            self.stats.drain_time += time.monotonic() - t0
+            self.stats.drains.append(record)
+        log.warning("replica %r drained (%s): engine loop phase %s open "
+                    "for %.3f s", rep.name, record["cause"], record["phase"],
+                    record["phase_s"])
+        with Span(self.stats, "drain_time", "serve.router.drain",
+                  replica=rep.name, phase=record["phase"] or "none"):
+            rep.engine.hard_kill()
+            rep.engine.resume()
+            if rep.thread is not None:
+                rep.thread.join()
+            self.heartbeat.forget(rep.name)
+            tickets = rep.engine.drain_tickets()
+            for ticket in tickets:
+                self._ship(ticket)
+            rep.engine.close()
+            rep.closed = True
+            with self._lock:
+                self.stats.replicas_killed += 1
 
     def _ship(self, ticket: MigrationTicket) -> None:
         """Serialize one ticket, pick a surviving target, push it through

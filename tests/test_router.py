@@ -219,6 +219,79 @@ def test_paused_replica_detected_and_drained(lm):
     assert_no_fleet_threads()
 
 
+def test_heartbeat_drain_records_the_open_loop_phase(lm, caplog, tmp_path):
+    """A replica drained for missed heartbeats names the engine loop phase
+    that kept it silent, and for how long it had been open: in
+    ``RouterStats.drains``, in the log, and on a ``serve.router.drain``
+    span. A paused loop waits inside ``serve.loop.hooks``."""
+    model, params = lm
+    topo = FleetTopology(n_replicas=2, heartbeat_timeout_s=60.0)
+    prompts = make_prompts(model, 4, seed=5)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with Router(model, params, fleet_cfg(), topology=topo,
+                    placement="least-loaded") as router:
+            rids = [router.submit(p, max_new=6) for p in prompts]
+            victim = router.replicas[0]
+            deadline = time.monotonic() + 120
+            busy = False
+            while not busy and time.monotonic() < deadline:
+                victim.engine.pause()
+                with victim.engine._lock:
+                    busy = bool(victim.engine._live)
+                if not busy:
+                    victim.engine.resume()
+                    time.sleep(0.005)
+            assert busy, "victim never picked up work"
+            # the loop reaches its pause wait, inside the hooks phase
+            while (victim.engine.phase or ("",))[0] != "serve.loop.hooks":
+                assert time.monotonic() < deadline
+                time.sleep(0.005)
+            time.sleep(0.05)
+            router.heartbeat.beat(victim.name,
+                                  now=time.monotonic() - 2 * 60.0 - 1)
+            router.wait(rids, timeout=300)
+            summ = router.summary()
+    finally:
+        jax.profiler.stop_trace()
+    assert len(summ["drains"]) == 1
+    drain = summ["drains"][0]
+    assert drain["replica"] == victim.name and drain["cause"] == "heartbeat"
+    assert drain["phase"] == "serve.loop.hooks"
+    assert drain["phase_s"] >= 0.05
+    assert any("serve.loop.hooks" in r.getMessage() and victim.name
+               in r.getMessage() for r in caplog.records)
+    from jax.profiler import ProfileData
+    xplane = next(tmp_path.rglob("*.xplane.pb"))
+    data = ProfileData.from_serialized_xspace(xplane.read_bytes())
+    names = {ev.name for plane in data.planes for line in plane.lines
+             for ev in line.events}
+    assert "serve.router.drain" in names
+    assert_no_fleet_threads()
+
+
+def test_a_crash_with_no_survivor_raises_the_crash(lm, monkeypatch):
+    """The only replica crashes: its drain has nowhere to ship, and the
+    router raises the crash itself, with the cause in its drain record."""
+    model, params = lm
+
+    def broken_compile(self, fn, args):
+        raise ValueError("program failed to build")
+    monkeypatch.setattr(Engine, "_compile", broken_compile)
+    topo = FleetTopology(n_replicas=1, heartbeat_timeout_s=60.0)
+    prompts = make_prompts(model, 2, seed=6)
+    with Router(model, params, fleet_cfg(), topology=topo) as router:
+        rids = [router.submit(p, max_new=4) for p in prompts]
+        with pytest.raises(ValueError, match="failed to build"):
+            router.wait(rids, timeout=120)
+        drains = router.summary()["drains"]
+    assert len(drains) == 1 and "failed to build" in drains[0]["cause"]
+    assert drains[0]["phase"] is None
+    assert_no_fleet_threads()
+
+
 def test_compile_longer_than_heartbeat_timeout_drains_nothing(
         lm, monkeypatch):
     """A full-size compile silences a replica's run loop for seconds. The

@@ -188,6 +188,29 @@ def test_stale_transfer_after_release_is_safe(lm):
     assert pol.priority(stale) < 0               # drains stale items first
 
 
+def test_a_decode_step_releases_the_cache_it_replaced(lm, monkeypatch):
+    """Every cache update replaces the whole cache; the one a decode step
+    replaced must be gone before the next iteration's paging and prefill
+    make more, or the device holds two caches between steps."""
+    model, params = lm
+    cfg = ServeConfig(max_len=48, batch_buckets=(2,), block_size=16)
+    prompts = [[1, 2, 3], [4, 5, 6, 7], [8, 9]]
+    live = []
+    prefill = Engine._prefill_admit
+
+    def counting_prefill(self, admits):
+        shapes = [leaf.shape for leaf in self.kv.cache.values()]
+        live.append(sum(a.shape in shapes for a in jax.live_arrays()))
+        return prefill(self, admits)
+    monkeypatch.setattr(Engine, "_prefill_admit", counting_prefill)
+    eng = Engine(model, params, cfg)
+    assert eng.generate(prompts, max_new=4) == oracle(lm, prompts,
+                                                      max_new=4, max_len=48)
+    # the third prompt is admitted after decode steps have run
+    assert len(live) == 2 and eng.stats.decode_steps > 0
+    assert live == [len(eng.kv.cache)] * 2, live
+
+
 # -------------------------------------------------------------- disk tier
 @pytest.mark.parametrize("policy", RELOAD_POLICY_NAMES)
 def test_tiered_kv_matches_oracle_every_policy(lm, policy):

@@ -196,15 +196,18 @@ class DiskStore:
     reclaimed by **compaction**: when dead bytes dominate the log
     (``compact_dead_fraction`` of the file, once it exceeds
     ``compact_min_bytes``), the live records are streamed into a fresh
-    log which atomically replaces the old one (``os.replace``), under
-    the same store lock every mutation already holds. A crash at any
-    instant leaves either the complete old log or the complete new one —
-    never a torn mixture — and in-flight readers holding the old read
-    handle retry against the new index (a generation counter guards the
-    swap). ``capacity`` (bytes, ``None`` = unbounded) makes :meth:`put`
-    refuse admissions that would overflow the tier with a
-    :class:`DiskFullError` — overwriting an existing key only charges
-    the delta."""
+    log which atomically replaces the old one (``os.replace``). The bulk
+    of that copy runs with the store lock released, so puts, drops and
+    reads go on meanwhile (:meth:`compact_if_due`); by default the put or
+    drop that crosses the threshold runs it, and with
+    ``compact_inline=False`` the owner runs it from a thread of its own.
+    A crash at any instant leaves either the complete old log or the
+    complete new one — never a torn mixture — and in-flight readers
+    holding the old read handle retry against the new index (a
+    generation counter guards the swap). ``capacity`` (bytes, ``None`` =
+    unbounded) makes :meth:`put` refuse admissions that would overflow
+    the tier with a :class:`DiskFullError` — overwriting an existing key
+    only charges the delta."""
 
     _ARR = "__arr__"              # spec field name for a bare-ndarray value
     _MAGIC = b"TNIP"
@@ -213,7 +216,8 @@ class DiskStore:
     def __init__(self, directory: str | os.PathLike | None = None, *,
                  capacity: int | None = None,
                  compact_dead_fraction: float | None = 0.5,
-                 compact_min_bytes: int = 1 << 20) -> None:
+                 compact_min_bytes: int = 1 << 20,
+                 compact_inline: bool = True) -> None:
         self._dir = pathlib.Path(directory) if directory is not None else None
         self._owns_dir = directory is None
         self.capacity = capacity
@@ -222,6 +226,9 @@ class DiskStore:
         # the size floor (small logs are cheaper to leave alone)
         self.compact_dead_fraction = compact_dead_fraction
         self.compact_min_bytes = compact_min_bytes
+        # False: puts and drops never compact, so none of them waits for a
+        # rewrite of the log; the owner calls compact_if_due() instead
+        self.compact_inline = compact_inline
         # key -> (log offset, payload nbytes, ((name, dtype, shape, nb), ...))
         self._files: dict[Any, tuple[int, int, tuple]] = {}
         self._log_path: pathlib.Path | None = None
@@ -244,6 +251,9 @@ class DiskStore:
         # on one, so they stay open until close()
         self._retired_fds: list[int] = []
         self._lock = lockcheck.make_lock("DiskStore")
+        # held for a whole compaction: one at a time, and close() waits
+        # for it (the copy reads the log without the store lock)
+        self._compact_lock = lockcheck.make_lock("DiskStore.compact")
 
     def _root(self) -> pathlib.Path:
         if self._dir is None:
@@ -305,7 +315,8 @@ class DiskStore:
                                            self.resident_bytes)
             if prev_entry is not None:   # the old record is now dead space
                 self.dead_bytes += self._HDR.size + prev
-                self._maybe_compact_locked()
+        if prev_entry is not None and self.compact_inline:
+            self.compact_if_due()
         return n
 
     def _read_blob(self, entry: tuple[int, int, tuple]):
@@ -394,25 +405,46 @@ class DiskStore:
                 return
             self.resident_bytes -= entry[1]
             self.dead_bytes += self._HDR.size + entry[1]
-            self._maybe_compact_locked()
+        if self.compact_inline:
+            self.compact_if_due()
 
     # ---- log compaction ----------------------------------------------
-    def _maybe_compact_locked(self) -> None:
-        """Lock held. Kick a compaction when dead bytes dominate the log.
-        Compaction is an *optimization*: any failure (I/O error, a torn
-        record in a log region we were about to discard anyway) leaves
-        the store fully functional on the old log, so errors are
-        swallowed here — the put/drop that triggered the pass must not
-        fail for it."""
-        if (self._wfd is None or self.compact_dead_fraction is None
-                or self._end < self.compact_min_bytes
-                or self.dead_bytes <
-                self.compact_dead_fraction * self._end):
-            return
+    def _compaction_due_locked(self) -> bool:
+        return (self._wfd is not None
+                and self.compact_dead_fraction is not None
+                and self._end >= self.compact_min_bytes
+                and self.dead_bytes >= self.compact_dead_fraction * self._end)
+
+    def compaction_due(self) -> bool:
+        """Whether dead bytes dominate the log (``compact_dead_fraction``
+        of the file, once it exceeds ``compact_min_bytes``)."""
+        with self._lock:
+            return self._compaction_due_locked()
+
+    def compact_if_due(self) -> bool:
+        """Rewrite the log when dead bytes dominate it; returns whether it
+        did. The live records are copied with the store lock released —
+        records are immutable and appends only extend the log — then the
+        lock is taken again to copy what was appended meanwhile, publish
+        the new log and swap the index. Compaction is an *optimization*:
+        any failure (I/O error, a torn record in a log region we were
+        about to discard anyway) leaves the store fully functional on the
+        old log, so errors are swallowed here — the put/drop that
+        triggered the pass must not fail for it."""
+        if not self._compact_lock.acquire(blocking=False):
+            return False                  # another thread is compacting
         try:
-            self._compact_locked()
-        except (OSError, ValueError):
-            pass
+            with self._lock:
+                if not self._compaction_due_locked():
+                    return False
+                live = sorted(self._files.items(), key=lambda kv: kv[1][0])
+            try:
+                self._compact(live)
+            except (OSError, ValueError):
+                return False
+            return True
+        finally:
+            self._compact_lock.release()
 
     def _publish_compaction(self, tmp: pathlib.Path,
                             path: pathlib.Path) -> None:
@@ -423,67 +455,92 @@ class DiskStore:
         crash-during-compaction tests."""
         os.replace(tmp, path)
 
-    def _compact_locked(self) -> None:
-        """Lock held. Stream the live records into a fresh log, fsync,
-        atomically publish, and swap the in-memory index to the new
-        offsets. The old read handle is retired, not closed: a
-        concurrent :meth:`get` may be mid-``pread`` on it (it will see
-        intact old-log bytes, notice the generation bump, and retry
-        against the new index)."""
-        assert self._log_path is not None and self._rfd is not None \
-            and self._wfd is not None
-        old_rfd, old_wfd, old_end = self._rfd, self._wfd, self._end
+    def _read_record(self, fd: int, off: int, n: int) -> bytes:
+        """One framed record, header included, read at ``off``."""
+        hdr = os.pread(fd, self._HDR.size, off)
+        if len(hdr) != self._HDR.size:
+            raise ValueError("torn record header")
+        magic, length = self._HDR.unpack(hdr)
+        if magic != self._MAGIC or length != n:
+            raise ValueError("bad record frame")
+        buf = os.pread(fd, n, off + self._HDR.size)
+        if len(buf) != n:
+            raise ValueError("torn record payload")
+        return hdr + buf
+
+    def _compact(self, live: list) -> None:
+        """``_compact_lock`` held, the store lock not. Stream ``live`` (the
+        index entries when the pass began, by offset) into a fresh log and
+        fsync it; then, under the store lock, append the records put since,
+        atomically publish, and swap the index to the new offsets. A record
+        dropped meanwhile is dead space in the new log. The old read handle
+        is retired, not closed: a concurrent :meth:`get` may be mid-``pread``
+        on it (it will see intact old-log bytes, notice the generation bump,
+        and retry against the new index)."""
+        assert self._log_path is not None and self._rfd is not None
+        old_rfd = self._rfd               # only compaction and close swap it
         tmp = self._log_path.with_name(self._log_path.name + ".compact")
-        entries = sorted(self._files.items(), key=lambda kv: kv[1][0])
         tfd: int | None = os.open(str(tmp),
                                   os.O_WRONLY | os.O_CREAT | os.O_TRUNC,
                                   0o644)
         try:
-            new_files: dict[Any, tuple[int, int, tuple]] = {}
+            copied: dict[Any, tuple[int, int, tuple, int]] = {}
             at = 0
-            for key, (off, n, spec) in entries:
-                hdr = os.pread(old_rfd, self._HDR.size, off)
-                if len(hdr) != self._HDR.size:
-                    raise ValueError("torn record header")
-                magic, length = self._HDR.unpack(hdr)
-                if magic != self._MAGIC or length != n:
-                    raise ValueError("bad record frame")
-                buf = os.pread(old_rfd, n, off + self._HDR.size)
-                if len(buf) != n:
-                    raise ValueError("torn record payload")
-                os.write(tfd, hdr + buf)
-                new_files[key] = (at, n, spec)
-                at += self._HDR.size + n
+            for key, (off, n, spec) in live:
+                rec = self._read_record(old_rfd, off, n)
+                os.write(tfd, rec)
+                copied[key] = (at, n, spec, off)
+                at += len(rec)
             os.fsync(tfd)
-            os.close(tfd)
-            tfd = None
-            self._publish_compaction(tmp, self._log_path)
+            with self._lock:
+                new_files: dict[Any, tuple[int, int, tuple]] = {}
+                late = []
+                for key, entry in self._files.items():
+                    c = copied.get(key)
+                    if c is not None and c[3] == entry[0]:
+                        new_files[key] = c[:3]
+                    else:                 # put since the pass began
+                        late.append((key, entry))
+                for key, (off, n, spec) in sorted(late,
+                                                  key=lambda kv: kv[1][0]):
+                    rec = self._read_record(old_rfd, off, n)
+                    os.write(tfd, rec)
+                    new_files[key] = (at, n, spec)
+                    at += len(rec)
+                if late:
+                    os.fsync(tfd)
+                os.close(tfd)
+                tfd = None
+                self._publish_compaction(tmp, self._log_path)
+                # committed on disk — swap handles and index. The old fds
+                # keep the pre-replace inode alive for any mid-read get.
+                old_wfd, old_end = self._wfd, self._end
+                self._wfd = os.open(
+                    str(self._log_path),
+                    os.O_WRONLY | os.O_CREAT | os.O_APPEND, 0o644)
+                try:
+                    self._rfd = os.open(str(self._log_path), os.O_RDONLY)
+                except BaseException:
+                    os.close(self._wfd)
+                    self._wfd = old_wfd
+                    raise
+                self._retired_fds += [old_rfd, old_wfd]
+                self._files = new_files
+                self._end = at
+                self.dead_bytes = at - sum(self._HDR.size + n
+                                           for _, n, _ in new_files.values())
+                self._gen += 1
+                self.n_compactions += 1
+                self.compacted_reclaimed_bytes += old_end - at
         except BaseException:
             # abort: the old log (and every handle on it) is untouched
             if tfd is not None:
                 os.close(tfd)
             tmp.unlink(missing_ok=True)
             raise
-        # committed on disk — swap handles and index. The old fds keep
-        # the pre-replace inode alive for any mid-read concurrent get.
-        self._wfd = os.open(str(self._log_path),
-                            os.O_WRONLY | os.O_CREAT | os.O_APPEND, 0o644)
-        try:
-            self._rfd = os.open(str(self._log_path), os.O_RDONLY)
-        except BaseException:
-            os.close(self._wfd)
-            self._wfd, self._rfd = old_wfd, old_rfd
-            raise
-        self._retired_fds += [old_rfd, old_wfd]
-        self._files = new_files
-        self._end = at
-        self.dead_bytes = 0
-        self._gen += 1
-        self.n_compactions += 1
-        self.compacted_reclaimed_bytes += old_end - at
 
     def close(self) -> None:
-        with self._lock:
+        with self._compact_lock, self._lock:
             self._files.clear()
             self.resident_bytes = 0
             self.dead_bytes = 0

@@ -22,7 +22,8 @@ rather than a caveat:
   their own engine classes (:data:`~repro.core.dispatch.D2H` /
   :data:`~repro.core.dispatch.H2D`) and overlap under decode, so steps
   never block on a transfer (paper §5). The main loop owns all cache
-  mutation; DMA threads only snapshot blocks and post completion events.
+  mutation; DMA threads only copy blocks between tiers (the h2d stream
+  stages a resume's blocks on the device) and post completion events.
 * **Nondeterministic reload order.** Which pending transfer a DMA stream
   services next is a :class:`~repro.core.dispatch.DispatchPolicy` decision:
   ``fixed`` replays block-creation order (the compile-time-order ablation —
@@ -239,7 +240,9 @@ class ServeStats:
     hook_time: float = 0.0            # on_step and the pause wait
     lock_wait_time: float = 0.0       # acquiring the engine lock
     events_time: float = 0.0          # completion events, kill checks
-    restore_time: float = 0.0         # restore_slot: a resume's h2d copy
+    restore_time: float = 0.0         # restore_slot: a resume's on-device
+    #                                   concatenate and scatter dispatch,
+    #                                   and the h2d copy of unstaged blocks
     restores: int = 0
     drop_time: float = 0.0            # drop_slot of a swapped-out slot
     admit_time: float = 0.0           # admission planning, prefill inputs
@@ -251,7 +254,14 @@ class ServeStats:
     d2h_copy_time: float = 0.0        # read_block (device -> host copy)
     d2h_copy_bytes: int = 0
     d2h_store_time: float = 0.0       # put_offload under the engine lock
+    h2d_copy_time: float = 0.0        # device_put of a reloaded block
+    h2d_copy_bytes: int = 0
+    h2d_staged_blocks: int = 0        # blocks restore_slot found staged on
+    #                                   the device by the h2d stream ...
+    h2d_unstaged_blocks: int = 0      # ... and blocks it copied itself
+    #                                   (the stream was at staging_cap)
     disk_io_time: float = 0.0         # spill, load and prefetch file I/O
+    disk_compact_time: float = 0.0    # rewrites of the disk tier's log
     d2h_wire_time: float = 0.0        # simulated wire sleeps, per stream
     h2d_wire_time: float = 0.0
     disk_wire_time: float = 0.0
@@ -557,6 +567,11 @@ class Engine:
             self.host = HostStore({})
             self._owns_host = True
         self._tiered = isinstance(self.host, TieredStore)
+        if self._tiered and self._owns_host:
+            # the disk stream compacts the disk tier's log (_compact_disk):
+            # inline, a drop under the engine lock would wait for a rewrite
+            # of the whole live tier, seconds at full size
+            self.host.disk.compact_inline = False
         # per-key reservation ledger: key -> (lease, charged bytes). A key
         # appears here from the moment its host-bound transfer is charged
         # until its host copy is spilled/popped — the release always uses
@@ -615,6 +630,9 @@ class Engine:
         self._disk: _DmaStream | None = None
         self._spill_inflight: set[tuple[int, int]] = set()
         self._prefetch_inflight: set[tuple[int, int]] = set()
+        # reloaded blocks the h2d stream copied onto the device, not yet
+        # applied by restore_slot; bounded by PagedKVCache.staging_cap
+        self._staged: set[tuple[int, int]] = set()
         self._idle_spins = 0            # consecutive no-progress stalls
         self._idle_pool_state = None    # last observed (pool used, grant)
         # ---- fleet / fault-injection seams (serve/router.py) ------------
@@ -1117,8 +1135,8 @@ class Engine:
             self._lock.release()
 
     # -------------------------------------------------- DMA service hooks
-    # (run on stream threads after the simulated wire time; they only read
-    # device blocks and post events — the main loop owns cache mutation)
+    # (run on stream threads after the simulated wire time; they only copy
+    # blocks and post events — the main loop owns cache mutation)
     def _service_d2h(self, tr: _Transfer) -> None:
         with self._lock:
             req = self.reqs.get(tr.rid)
@@ -1159,15 +1177,33 @@ class Engine:
             self._wake.notify_all()
 
     def _service_h2d(self, tr: _Transfer) -> None:
-        # only a host reference is fetched here: the host-to-device copy
-        # of a resume happens in restore_slot, on the run loop
-        data = self.host.get_offload((tr.rid, tr.blk))
+        """Copy a reloaded block onto the cache's device, off the engine
+        lock, so the copy overlaps decode; the run loop's ``restore_slot``
+        then only concatenates and scatters. Staged bytes are bounded by
+        ``PagedKVCache.staging_cap``: a block past it is posted as its
+        host reference and ``restore_slot`` copies it. The bound never
+        waits, so the stream has no new blocking edge."""
+        key = (tr.rid, tr.blk)
+        data = self.host.get_offload(key)
+        with self._lock:
+            stage = ((len(self._staged) + 1) * self.kv.block_nbytes
+                     <= self.kv.staging_cap)
+            if stage:
+                self._staged.add(key)
+        if stage:
+            with Span(self.stats, "h2d_copy_time", "serve.h2d.copy",
+                      rid=tr.rid, blk=tr.blk, nbytes=tr.nbytes):
+                data = jax.block_until_ready(
+                    {k: self.kv.put(v) for k, v in data.items()})
+            self.stats.h2d_copy_bytes += tr.nbytes
         with self._lock:
             self.stats.reload_bytes += tr.nbytes
             req = self.reqs.get(tr.rid)
             if req is not None:
                 req.inflight.discard(tr.blk)
                 self._events.append(("reload", tr.rid, tr.blk, data))
+            else:                                     # released mid-flight
+                self._staged.discard(key)
             self._wake.notify_all()
 
     def _service_disk(self, tr: _Transfer) -> None:
@@ -1180,6 +1216,7 @@ class Engine:
         admissions hold the same lock, so a swap-in can never claim a
         block mid-spill and drag the disk read onto the h2d lane via
         read-through. One block's write is cheap; the invariant is not."""
+        self._compact_disk()
         key = (tr.rid, tr.blk)
         meta = dict(rid=tr.rid, blk=tr.blk, nbytes=tr.nbytes)
         if tr.disk_op == "prefetch":
@@ -1281,6 +1318,15 @@ class Engine:
                 req.inflight.discard(tr.blk)
             self._wake.notify_all()
 
+    def _compact_disk(self) -> None:
+        """Rewrite the disk tier's log when dead bytes dominate it, on the
+        disk stream and off the engine lock (an engine-owned store never
+        compacts inside a drop). Puts, drops and reads go on meanwhile."""
+        disk = self.host.disk
+        if not disk.compact_inline and disk.compaction_due():
+            with Span(self.stats, "disk_compact_time", "serve.disk.compact"):
+                disk.compact_if_due()
+
     # ------------------------------------------------------ event applies
     def _apply_events_locked(self) -> None:
         """Apply the DMA streams' completion events. The device work they
@@ -1296,6 +1342,7 @@ class Engine:
                     _, rid, blk, data = ev
                     req = self.reqs.get(rid)
                     if req is None or req.state != RELOADING:
+                        self._staged.discard((rid, blk))
                         continue
                     req.reload_data[blk] = data
                     req.pending_reload.discard(blk)
@@ -1322,6 +1369,12 @@ class Engine:
                                  rid=req.rid, blocks=len(blocks)):
                 self.kv.restore_slot(req.slot, blocks)
                 self.stats.restores += 1
+                for blk in range(len(blocks)):
+                    if (req.rid, blk) in self._staged:
+                        self._staged.discard((req.rid, blk))
+                        self.stats.h2d_staged_blocks += 1
+                    else:
+                        self.stats.h2d_unstaged_blocks += 1
                 # the tail block keeps growing after resume: its host copy
                 # is stale from now on and must re-offload (every cold
                 # block's copy stays valid — reuse_host_copy). Popped only
@@ -1533,6 +1586,7 @@ class Engine:
         req.pending_reload.clear()
         for blk in range(self.kv.n_token_blocks(req.pos)):
             self._block_seq.pop((req.rid, blk), None)
+            self._staged.discard((req.rid, blk))
         # in-flight d2h mirrors see state == DONE and drop their payload
         # (and release their reservations); in-flight prefetches release
         # theirs on completion when no host bytes landed

@@ -16,6 +16,10 @@ offload unit (NEO / SpecOffload direction, PAPERS.md):
 
 * :meth:`read_block`   — device→host snapshot of one block (a d2h payload);
 * :meth:`write_block`  — host→device restore of one block (an h2d payload);
+* :meth:`restore_slot` — apply a resumed request's blocks to its slot in one
+  scatter. The engine's h2d stream copies each block onto the device as
+  it reloads, within :attr:`staging_cap`; a block past the cap arrives
+  as host arrays and is copied here, on the run loop;
 * :meth:`drop_slot`    — zero a slot's extents when its request is swapped
   out, so a missed reload computes on zeros instead of silently reusing
   stale bytes (the serving analogue of ``SlotTable`` read-validation);
@@ -117,7 +121,8 @@ class PagedKVCache:
                 for k, leaf in leaves.items()}
 
     def put(self, x) -> jax.Array:
-        """Copy a host array onto the cache's device."""
+        """Copy a host array onto the cache's device (an array already
+        there is returned as it is)."""
         return jax.device_put(x, self.device)
 
     def write_block(self, slot: int, blk: int,
@@ -126,19 +131,25 @@ class PagedKVCache:
         self.cache = {k: leaf.at[:, slot, lo:hi].set(self.put(data[k]))
                       for k, leaf in self.cache.items()}
 
-    def restore_slot(self, slot: int,
-                     blocks: list[dict[str, np.ndarray]]) -> None:
+    @property
+    def staging_cap(self) -> int:
+        """Device bytes of reloaded blocks the h2d stream may hold staged
+        ahead of their ``restore_slot``: two slots' full extent."""
+        return 2 * self.n_blocks * self.block_nbytes
+
+    def restore_slot(self, slot: int, blocks: list[dict[str, Any]]) -> None:
         """Apply a resumed request's reloaded blocks 0..n-1 in ONE per-leaf
         scatter — block-wise application would copy every cache leaf once
-        per block. This is where a resume's host-to-device copy happens
-        (the h2d stream only fetches host references): the host-side
-        concatenate, the ``device_put`` and the scatter's dispatch, timed
-        by the engine's ``serve.kv.restore_slot`` span."""
+        per block. A block comes either staged on the device by the h2d
+        stream or as host arrays, which ``put`` copies here; ``put`` leaves
+        a device block where it is, so both run the same device programs:
+        a concatenate per leaf, then the scatter. Timed by the engine's
+        ``serve.kv.restore_slot`` span: with every block staged, that is
+        the two dispatches alone."""
         span = len(blocks) * self.block_size
         self.cache = {
-            k: leaf.at[:, slot, :span].set(self.put(
-                np.concatenate([np.asarray(b[k]) for b in blocks],
-                               axis=1)).astype(leaf.dtype))
+            k: leaf.at[:, slot, :span].set(jnp.concatenate(
+                [self.put(b[k]) for b in blocks], axis=1).astype(leaf.dtype))
             for k, leaf in self.cache.items()}
 
     def drop_slot(self, slot: int) -> None:

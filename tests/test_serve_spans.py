@@ -39,9 +39,20 @@ LOOP_SPANS = {
     "serve.decode.emit": "emit_time",
     "serve.loop.stall": "stall_time",
 }
-# the DMA and disk streams' spans, on their own threads
-STREAM_SPANS = ("serve.d2h.copy", "serve.d2h.store", "serve.disk.spill",
-                "serve.disk.load", "serve.disk.prefetch", "serve.dma.wire")
+# the DMA and disk streams' spans, on their own threads, each with the
+# ServeStats counters its time goes to
+WIRE = ("d2h_wire_time", "h2d_wire_time", "disk_wire_time")
+STREAM_SPANS = {
+    "serve.d2h.copy": ("d2h_copy_time",),
+    "serve.d2h.store": ("d2h_store_time",),
+    "serve.h2d.copy": ("h2d_copy_time",),
+    "serve.disk.spill": ("disk_io_time",),
+    "serve.disk.load": ("disk_io_time",),
+    "serve.disk.prefetch": ("disk_io_time",),
+    "serve.dma.wire": WIRE,
+}
+# the annotation around each engine's run() in the traced fixture
+RUN_SPAN = "test.run"
 
 PROMPTS = [list(range(1, 25)), list(range(30, 48)), [7, 8, 9, 10, 11]]
 
@@ -66,18 +77,20 @@ def tiered_cfg(lm, host_blocks: int) -> ServeConfig:
 
 
 def serve(lm, cfg: ServeConfig) -> tuple[Engine, float]:
-    """Serve ``PROMPTS``; returns the closed engine and run()'s wall time."""
+    """Serve ``PROMPTS``; returns the closed engine and run()'s wall time.
+    The run lies in a ``RUN_SPAN`` trace event."""
     with Engine(*lm, cfg) as eng:
         for p in PROMPTS:
             eng.submit(p, max_new=8)
-        t = time.perf_counter()
-        eng.run()
-        return eng, time.perf_counter() - t
+        with jax.profiler.TraceAnnotation(RUN_SPAN):
+            t = time.perf_counter()
+            eng.run()
+            return eng, time.perf_counter() - t
 
 
 def events(path: str):
-    """Every ``serve.*`` host event of the trace: (thread line, name,
-    start s, duration s, stats)."""
+    """Every ``serve.*`` and ``RUN_SPAN`` host event of the trace: (thread
+    line, name, start s, duration s, stats)."""
     from jax.profiler import ProfileData
     with open(path, "rb") as f:
         data = ProfileData.from_serialized_xspace(f.read())
@@ -89,7 +102,8 @@ def events(path: str):
                 continue
             for i, line in enumerate(plane.lines):
                 for ev in line.events:
-                    if ev.name.startswith("serve."):
+                    if (ev.name.startswith("serve.")
+                            or ev.name == RUN_SPAN):
                         out.append(((plane.name, i), ev.name,
                                     ev.start_ns * 1e-9,
                                     ev.duration_ns * 1e-9, dict(ev.stats)))
@@ -151,18 +165,35 @@ def test_loop_phase_spans_match_their_counters(traced):
         assert traced_s - counted <= 0.05 * traced_s + slack, name
 
 
+@pytest.mark.parametrize("counters", sorted(set(STREAM_SPANS.values())))
+def test_stream_spans_match_their_counters(traced, counters):
+    """A stream's counters hold the total of the spans that fill them, as
+    a loop phase's counter does."""
+    runs, _, evs = traced
+    names = [n for n, c in STREAM_SPANS.items() if c == counters]
+    slack = 4 * sys.getswitchinterval()
+    traced_s = sum(d for _, n, _, d, _ in evs if n in names)
+    counted = sum(getattr(eng.stats, c) for eng, _ in runs for c in counters)
+    assert 0 < counted <= traced_s + 1e-4, names
+    assert traced_s - counted <= 0.05 * traced_s + slack, names
+
+
 def test_loop_phases_add_up_to_the_loop_wall_time(traced):
-    """On each loop thread, from its first phase's start to its last
-    phase's end, the phases leave no gap; the counters hold the same
-    total."""
+    """In each run(), from its first phase's start to its last phase's
+    end, the phases leave no gap; the counters hold the same total. The
+    runs share a thread, so each is measured inside its own ``RUN_SPAN``:
+    the time between two runs (one engine's shutdown, the next one's
+    construction) belongs to no loop."""
     runs, _, evs = traced
     assert set(LOOP_SPANS.values()) == set(LOOP_PHASES)
-    lines: dict = {}
-    for line, name, s, d, _ in evs:
-        if name in LOOP_SPANS:
-            lines.setdefault(line, []).append((s, s + d))
+    bounds = [(line, s, s + d) for line, name, s, d, _ in evs
+              if name == RUN_SPAN]
+    assert len(bounds) == len(runs)
     covered = wall = 0.0
-    for spans in lines.values():
+    for run_line, lo, hi in bounds:
+        spans = [(s, s + d) for line, name, s, d, _ in evs
+                 if name in LOOP_SPANS and line == run_line
+                 and lo <= s and s + d <= hi]
         wall += max(e for _, e in spans) - min(s for s, _ in spans)
         covered += sum(e - s for s, e in spans)
     assert covered == pytest.approx(wall, rel=0.05)
@@ -178,6 +209,8 @@ def test_counters_fill_with_the_profiler_off(lm):
         assert getattr(st, counter) > 0, counter
     assert st.d2h_copy_time > 0 and st.d2h_copy_bytes >= st.offload_bytes > 0
     assert st.d2h_store_time > 0 and st.disk_io_time > 0
+    assert st.h2d_copy_time > 0 and st.h2d_copy_bytes > 0
+    assert st.h2d_staged_blocks > 0 and st.h2d_unstaged_blocks == 0
     assert st.d2h_wire_time > 0 and st.disk_wire_time > 0
     assert st.admissions == len(PROMPTS) and st.queue_time > 0
     assert st.restores >= st.resumes > 0
@@ -190,13 +223,14 @@ def test_transfer_spans_carry_request_and_block(traced):
     _, _, evs = traced
     d2h = [stats for _, n, _, _, stats in evs
            if n in ("serve.d2h.copy", "serve.d2h.store")]
-    assert d2h
-    for stats in d2h:
+    h2d = [stats for _, n, _, _, stats in evs if n == "serve.h2d.copy"]
+    assert d2h and h2d
+    for stats in d2h + h2d:
         assert {"rid", "blk", "nbytes"} <= set(stats), stats
     spills = [stats for _, n, _, _, stats in evs if n == "serve.disk.spill"]
     assert spills and all(s.get("under_lock") == 1 for s in spills)
     rids = {s["rid"] for s in d2h}
-    assert rids <= {0, 1, 2}
+    assert rids <= {0, 1, 2} and {s["rid"] for s in h2d} <= rids
     restores = [s for _, n, _, _, s in evs if n == "serve.kv.restore_slot"]
     assert restores and all(s["rid"] in rids and s["blocks"] >= 1
                             for s in restores)

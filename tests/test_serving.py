@@ -6,6 +6,8 @@ The oracle is :func:`repro.serve.naive_generate` — an unbatched prefill +
 single-row decode loop with the engine's (seed, rid, position) key
 schedule. Every engine configuration (bucketing, padding, offload,
 preemption, reload order) must reproduce it token-for-token."""
+import threading
+
 import jax
 import numpy as np
 import pytest
@@ -281,6 +283,35 @@ def test_tiered_kv_roomy_host_never_touches_disk(lm):
         assert out == oracle(lm, prompts, max_new=6, max_len=64)
         assert eng.stats.disk_spill_bytes == 0
         assert eng.stats.disk_load_bytes == 0
+
+
+def test_disk_log_compacts_on_the_disk_stream(lm, monkeypatch):
+    """The engine's disk tier never compacts inside a drop: drops happen
+    under the engine lock, and a rewrite of the live tier takes seconds at
+    full size. The disk stream compacts it, off the lock, and tokens stay
+    the oracle's."""
+    from repro.core.stores import DiskStore
+    model, params = lm
+    prompts = [list(range(1, 25)), list(range(30, 48)), [7, 8, 9, 10, 11]]
+    cfg = ServeConfig(max_len=64, batch_buckets=(1,), block_size=8,
+                      offload=True, hot_window=0, preempt_every=3,
+                      h2d_bw=500e6, d2h_bw=500e6,
+                      host_kv_bytes=1, disk_bw=300e6)
+    threads = []
+    compact = DiskStore._compact
+
+    def recording_compact(self, live):
+        threads.append(threading.current_thread().name)
+        return compact(self, live)
+    monkeypatch.setattr(DiskStore, "_compact", recording_compact)
+    with Engine(model, params, cfg) as eng:
+        assert not eng.host.disk.compact_inline
+        eng.host.disk.compact_min_bytes = 1
+        out = eng.generate(prompts, max_new=8)
+        assert out == oracle(lm, prompts, max_new=8, max_len=64)
+        assert eng.host.disk.n_compactions > 0
+        assert eng.stats.disk_compact_time > 0
+    assert threads and set(threads) == {"serve-dma-disk"}
 
 
 # ------------------------------------------------------------ shared pool

@@ -479,6 +479,106 @@ class TestCompaction:
         np.testing.assert_array_equal(ds.get("churn"), np.full(256, 9.0))
         ds.close()
 
+    def test_writes_during_the_unlocked_copy_survive_compaction(self):
+        """The bulk copy runs without the store lock: a put, a re-put and a
+        drop that land in the middle of it are all honoured by the
+        published log, and its dead-byte count is exact."""
+        ds = DiskStore(compact_min_bytes=1, compact_inline=False)
+        a, b, c = (np.full(256, float(i)) for i in range(3))
+        ds.put("a", a)
+        ds.put("b", b)
+        ds.put("gone", c)
+        for i in range(3):
+            ds.put(("junk", i), c)
+            ds.drop(("junk", i))         # dead bytes now dominate the log
+        assert ds.n_compactions == 0 and ds.compaction_due()
+        orig = DiskStore._read_record
+        mutated = []
+
+        def seam(self, fd, off, n):
+            if not mutated:              # first record of the bulk copy
+                mutated.append(True)
+                self.put("new", 2 * a)   # appended past the snapshot
+                self.put("b", 3 * b)     # re-put: the copied record is stale
+                self.drop("gone")        # copied, then dropped
+            return orig(self, fd, off, n)
+
+        ds._read_record = seam.__get__(ds)
+        assert ds.compact_if_due()
+        assert ds.n_compactions == 1 and mutated
+        np.testing.assert_array_equal(ds.get("a"), a)
+        np.testing.assert_array_equal(ds.get("b"), 3 * b)
+        np.testing.assert_array_equal(ds.get("new"), 2 * a)
+        assert "gone" not in ds
+        live = sum(ds._HDR.size + n for _, n, _ in ds._files.values())
+        assert ds.dead_bytes == ds._end - live > 0
+        assert os.stat(ds._log_path).st_size == ds._end
+        ds.close()
+
+    def test_concurrent_writers_and_compactions_lose_nothing(self):
+        """More writer threads than cores put, re-put and drop their own
+        keys while compactions run inline and from a thread of their own;
+        every key ends with its last value, and no dropped key returns."""
+        import sys
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        ds = DiskStore(compact_min_bytes=1)
+        n_threads = (os.cpu_count() or 1) + 2
+        want: list[dict] = [{} for _ in range(n_threads)]
+        stop = threading.Event()
+
+        def writer(t):
+            rng = pyrandom.Random(t)
+            for i in range(60):
+                key = (t, rng.randrange(4))
+                if rng.random() < 0.3:
+                    ds.drop(key)
+                    want[t].pop(key, None)
+                else:
+                    ds.put(key, np.full(64, float(i)))
+                    want[t][key] = float(i)
+
+        def compactor():
+            while not stop.is_set():
+                ds.compact_if_due()
+                time.sleep(1e-4)     # leave the writers' own turns free
+        try:
+            threads = [threading.Thread(target=writer, args=(t,))
+                       for t in range(n_threads)]
+            extra = threading.Thread(target=compactor)
+            extra.start()
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(60)
+            stop.set()
+            extra.join(60)
+            assert not any(th.is_alive() for th in threads + [extra])
+        finally:
+            sys.setswitchinterval(switch)
+        assert ds.n_compactions > 0
+        for t in range(n_threads):
+            for k in range(4):
+                key = (t, k)
+                if key in want[t]:
+                    np.testing.assert_array_equal(
+                        ds.get(key), np.full(64, want[t][key]))
+                else:
+                    assert key not in ds
+        live = sum(ds._HDR.size + n for _, n, _ in ds._files.values())
+        assert ds.dead_bytes == ds._end - live
+        ds.close()
+
+    def test_no_inline_compaction_when_the_owner_compacts(self):
+        ds = DiskStore(compact_min_bytes=1, compact_inline=False)
+        for i in range(8):
+            ds.put("churn", np.full(256, float(i)))
+        ds.drop("churn")
+        assert ds.n_compactions == 0 and ds.compaction_due()
+        assert ds.compact_if_due() and not ds.compaction_due()
+        assert ds._end == 0 and ds.dead_bytes == 0
+        ds.close()
+
     def test_reader_paused_across_compaction_retries(self):
         """A get() that resolved its index entry, then lost the CPU while
         a compaction rewrote the log, reads at a stale offset of the NEW

@@ -194,6 +194,29 @@ class Run:
         return sum(self.in_window(t) for ts in self.tokens.values()
                    for t in ts)
 
+    def token_gaps(self) -> list[float]:
+        """Seconds between consecutive output tokens of one request, both
+        inside the window; a gap still open at the close counts with its
+        length so far."""
+        gaps = []
+        for rid, times in self.tokens.items():
+            inside = [t for t in times if self.in_window(t)]
+            gaps += list(np.diff(inside))
+            if inside and self.done.get(rid, self.t1) >= self.t1:
+                gaps.append(self.t1 - inside[-1])
+        return gaps
+
+    def first_token_waits(self) -> list[float]:
+        """Each request due in the window: seconds from when it was due
+        to its first token, or to the window's close where that comes
+        first."""
+        out = []
+        for rid, (_, due, _) in self.sent.items():
+            if self.in_window(due):
+                first = (self.tokens.get(rid) or [self.t1])[0]
+                out.append(min(first, self.t1) - due)
+        return out
+
     def steps(self, kind: str) -> list[tuple[float, list[int]]]:
         """Decode steps or prefill calls in the window, each as (time,
         positions of its rows), grouped by engine and step count."""
@@ -397,12 +420,13 @@ def passed(checks: dict) -> bool:
 # --------------------------------------------------------------------- run
 def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
              t_start: float, require_chips: bool = True,
-             peaks: dict | None = None, on_sample=None) -> dict:
+             peaks: dict | None = None, on_sample=None, on_run=None) -> dict:
     """One run. Prints readings on earlier lines and returns the result
     object (the caller prints it last). ``require_chips=False`` and
     ``peaks`` let a test drive the rest of a run on the CPU;
     ``on_sample(params, sample)`` sees the checked requests and the
-    weights once the check is done."""
+    weights once the check is done, and ``on_run(run)`` the ``Run`` that
+    the readers read."""
     import jax
     from bench import devtrace
     from bench.traffic import Pool
@@ -521,8 +545,10 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
           f"{d('swaps') / max(n_done, 1)} offload_bytes {d('offload_bytes')} "
           f"reload_bytes {d('reload_bytes')} disk_spill_bytes "
           f"{d('disk_spill_bytes')} disk_load_bytes {d('disk_load_bytes')}")
-    late = [sent - due for _, due, sent in run.sent.values()]
-    print(f"load: requests_sent {len(run.sent)} lateness_p99_ms "
+    late = [sent - due for _, due, sent in run.sent.values()
+            if run.in_window(due)]
+    print(f"load: requests_sent {len(run.sent)} due_in_window {len(late)} "
+          f"lateness_max_ms {1e3 * max(late, default=0.0)} lateness_p99_ms "
           f"{1e3 * percentile(late, 99) if late else 0.0}")
     print("logits_recorded: 0 requests; the window stamps token times only "
           "and the check re-scores served tokens after the window")
@@ -541,6 +567,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
             "device_ops": [[n, s] for n, (s, _) in progs[:10]],
             "idle_gaps": [[n, s] for n, s in run.trace.idle_gaps(10)]}
     result["checks"] = checks
+    if on_run is not None:
+        on_run(run)
     return result
 
 

@@ -5,12 +5,5 @@ import numpy as np
 
 
 def read(run):
-    gaps = []
-    for rid, times in run.tokens.items():
-        inside = [t for t in times if run.in_window(t)]
-        gaps += list(np.diff(inside))
-        if inside and run.done.get(rid, run.t1) >= run.t1:
-            gaps.append(run.t1 - inside[-1])
-    if not gaps:
-        return None
-    return 1e3 * float(np.percentile(gaps, 99))
+    gaps = run.token_gaps()
+    return 1e3 * float(np.percentile(gaps, 99)) if gaps else None
